@@ -159,7 +159,7 @@ def sweep_mlp_shape(B: int, L: int, g: int, Cl: int, k: int, quick: bool,
     # dispatch today (same resolution path, like sweep_shape's use of
     # _fused_tiles), not an arbitrary grid point — default_us documents
     # the win over the current dispatch
-    dtb, dtl, _, _ = ops._mlp_tiles(B, L, C, Cl, interp)
+    dtb, dtl, _, _ = ops._mlp_tiles(B, L, bank, 4, k, interp)
     default = {"tb": dtb, "tl": dtl}
     if interp:
         cands = [{"tb": tb, "tl": Lp}

@@ -42,7 +42,7 @@ def serving_throughput(rows: list, n_points: int = 120_000,
         out = hybrid_query(hyb, q, force_path=force)
         acc = float(np.asarray(out.leaf_accesses).mean())
         # bytes touched ≈ leaf accesses × leaf tile bytes
-        tile = dtree.leaf_entries.shape[1] * 2 * 4
+        tile = dtree.leaf_entries.shape[2] * 2 * 4
         rows.append((f"serve_{force}_qps", batch / dtm,
                      f"leaf_acc={acc:.2f},tile_bytes={tile}"))
 
@@ -152,7 +152,7 @@ def traversal_micro(rows: list, B: int = 256, L: int = 2048,
     tree = DeviceTree(
         levels=tuple(Level(mbrs=m, parent=p)
                      for m, p in zip(mbrs, parents)),
-        leaf_entries=jnp.zeros((L, 8, 2), jnp.float32),
+        leaf_entries=jnp.zeros((L, 2, 8), jnp.float32),
         leaf_entry_ids=jnp.zeros((L, 8), jnp.int32),
         leaf_counts=jnp.zeros((L,), jnp.int32),
         n_points=0, max_entries=fanout)
@@ -212,7 +212,7 @@ def compaction_micro(rows: list, B: int = 256, L: int = 2048,
     tree = DeviceTree(
         levels=tuple(Level(mbrs=m, parent=p)
                      for m, p in zip(mbrs, parents)),
-        leaf_entries=jnp.asarray(rng.uniform(-1, 1, (L, 8, 2)), jnp.float32),
+        leaf_entries=jnp.asarray(rng.uniform(-1, 1, (L, 2, 8)), jnp.float32),
         leaf_entry_ids=jnp.zeros((L, 8), jnp.int32),
         leaf_counts=jnp.full((L,), 8, jnp.int32),
         n_points=0, max_entries=fanout)
@@ -318,7 +318,7 @@ def ai_fusion_micro(rows: list, B: int = 256, L: int = 2048, g: int = 4,
             np.concatenate([lo2 := rng.uniform(-1, 1, (L, 2)),
                             lo2 + 0.2], 1), jnp.float32),
             parent=jnp.zeros((L,), jnp.int32)),),
-        leaf_entries=jnp.asarray(rng.uniform(-1, 1, (L, M, 2)), jnp.float32),
+        leaf_entries=jnp.asarray(rng.uniform(-1, 1, (L, 2, M)), jnp.float32),
         leaf_entry_ids=jnp.asarray(np.arange(L * M).reshape(L, M),
                                    jnp.int32),
         leaf_counts=jnp.full((L,), M, jnp.int32), n_points=L * M,
@@ -389,7 +389,7 @@ def scheduler_bench(rows: list, Q: int = 2048, batch: int = 256,
     tree = DeviceTree(
         levels=tuple(Level(mbrs=m, parent=p)
                      for m, p in zip(mbrs, parents)),
-        leaf_entries=jnp.asarray(rng.uniform(-1, 1, (L, 8, 2)), jnp.float32),
+        leaf_entries=jnp.asarray(rng.uniform(-1, 1, (L, 2, 8)), jnp.float32),
         leaf_entry_ids=jnp.zeros((L, 8), jnp.int32),
         leaf_counts=jnp.full((L,), 8, jnp.int32),
         n_points=0, max_entries=fanout)
@@ -783,7 +783,7 @@ def kernel_micro(rows: list) -> None:
     rows.append(("mbr_intersect_1024x4096_us", dtm * 1e6,
                  f"{1024*4096/dtm/1e9:.2f}Gpairs/s"))
 
-    entries = jnp.asarray(rng.uniform(-1, 1, (4096, 256, 2)), jnp.float32)
+    entries = jnp.asarray(rng.uniform(-1, 1, (4096, 2, 256)), jnp.float32)
     idx = jnp.asarray(rng.integers(0, 4096, (256, 32)), jnp.int32)
     val = jnp.ones((256, 32), jnp.int32)
     dtm = _time(lambda: ops.leaf_refine(q[:256], entries, idx, val))

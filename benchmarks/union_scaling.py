@@ -46,7 +46,7 @@ def _child(n_shards: int, reps: int) -> None:
     wl = labels.make_workload(dtree, qs)
     hyb, _ = build.fit_airtree(dtree, wl, kind="knn", grid_sizes=(8,))
 
-    mesh = jax.make_mesh((1, n_shards), ("data", "model"))
+    mesh = pmesh.make_mesh((1, n_shards), ("data", "model"))
     hyb_p = engine.pad_tree_for_sharding(hyb, n_shards)
     B = 256
     q = jnp.asarray(wl.queries[:B])
@@ -56,7 +56,7 @@ def _child(n_shards: int, reps: int) -> None:
         step = engine.make_serve_step(mesh, engine.EngineConfig(
             max_visited=64, max_pred=32, score_union=union), kind="knn")
         fn = jax.jit(lambda q, step=step: step(hyb_p, q))
-        with pmesh.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             stats[union] = fn(q)
             jax.block_until_ready(stats[union])   # compile + warm
             ts = []

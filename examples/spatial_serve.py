@@ -18,6 +18,7 @@ from repro.core import build, device_tree, engine, labels
 from repro.core.hybrid import hybrid_query
 from repro.core.rtree import RTree
 from repro.launch import mesh as pmesh
+from repro.launch.compile_cache import enable_compile_cache
 from repro.data import synth
 
 parser = argparse.ArgumentParser()
@@ -33,6 +34,9 @@ parser.add_argument("--repack-every", type=int, default=0,
                     help="online repack once this many inserts are staged")
 parser.add_argument("--distributed", action="store_true")
 args = parser.parse_args()
+print(f"# compile cache: {enable_compile_cache()}")
+# the kernels serve on a TPU; elsewhere the jnp reference path does
+on_tpu = jax.default_backend() == "tpu"
 
 all_points = synth.tweets_like(args.points, seed=0)
 n_ins = int(round(args.insert_rate * args.points))
@@ -63,7 +67,8 @@ if inserts is not None:
     from repro.core import schedule
     from repro.core.monitor import FreshServer
     server = FreshServer(points, hybrid, delta_cap=max(64, n_ins),
-                         max_visited=256, max_results=1024)
+                         max_visited=256, max_results=1024,
+                         use_kernel=on_tpu)
     stream = workload.queries[
         np.resize(order, args.batches * args.batch_size)]
     t0 = time.time()
@@ -85,9 +90,10 @@ if inserts is not None:
 step = None
 if args.distributed and len(jax.devices()) > 1:
     n = len(jax.devices())
-    mesh = jax.make_mesh((max(1, n // 2), 2), ("data", "model"))
+    mesh = pmesh.make_mesh((max(1, n // 2), 2), ("data", "model"))
     hybrid_s = engine.pad_tree_for_sharding(hybrid, 2)
-    step = engine.make_serve_step(mesh, engine.EngineConfig(), kind="knn")
+    step = engine.make_serve_step(
+        mesh, engine.EngineConfig(use_kernel=on_tpu), kind="knn")
 
 served = 0
 accesses = 0.0
@@ -101,15 +107,16 @@ for b in range(args.batches):
         take = np.concatenate([take, order[:args.batch_size - take.size]])
     q = jnp.asarray(workload.queries[take])
     if step is not None:
-        with pmesh.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             out = step(hybrid_s, q)
         acc = np.asarray(out.leaf_accesses)
         ai = np.asarray(out.used_ai)
     else:
-        out = hybrid_query(hybrid, q)
+        out = hybrid_query(hybrid, q, use_kernel=on_tpu)
         acc = np.asarray(out.leaf_accesses)
         ai = np.asarray(out.used_ai)
-    base = np.asarray(hybrid_query(hybrid, q, force_path="r").leaf_accesses)
+    base = np.asarray(hybrid_query(hybrid, q, force_path="r",
+                                   use_kernel=on_tpu).leaf_accesses)
     served += args.batch_size
     accesses += acc.sum()
     baseline += base.sum()

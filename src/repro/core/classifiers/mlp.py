@@ -21,6 +21,11 @@ import numpy as np
 
 from repro.core.celldata import CellDataset
 
+# Every bank matmul runs at full f32 precision — training, the dense
+# oracle and the fused kernel then agree on the thresholded labels (a
+# TPU's default f32 matmul rounds operands to bf16).
+F32 = jax.lax.Precision.HIGHEST
+
 
 @jax.tree_util.register_dataclass
 @dataclasses.dataclass(frozen=True)
@@ -72,8 +77,10 @@ def cell_logits(bank: MLPBank, feats: jnp.ndarray) -> jnp.ndarray:
     """Dense all-cells forward: feats [..., B, F] → logits [..., B, C, Cl]."""
     x = (feats - bank.mu) / bank.sd
     h = jnp.maximum(
-        jnp.einsum("...bf,cfh->...bch", x, bank.w1) + bank.b1, 0.0)
-    return jnp.einsum("...bch,chl->...bcl", h, bank.w2) + bank.b2
+        jnp.einsum("...bf,cfh->...bch", x, bank.w1, precision=F32) + bank.b1,
+        0.0)
+    return jnp.einsum("...bch,chl->...bcl", h, bank.w2,
+                      precision=F32) + bank.b2
 
 
 def cell_logits_for(bank: MLPBank, feats: jnp.ndarray,
@@ -88,8 +95,9 @@ def cell_logits_for(bank: MLPBank, feats: jnp.ndarray,
     b1 = bank.b1[cell_ids]
     w2 = bank.w2[cell_ids]                    # [B, S, H, Cl]
     b2 = bank.b2[cell_ids]
-    h = jnp.maximum(jnp.einsum("bf,bsfh->bsh", x, w1) + b1, 0.0)
-    return jnp.einsum("bsh,bshl->bsl", h, w2) + b2
+    h = jnp.maximum(jnp.einsum("bf,bsfh->bsh", x, w1, precision=F32) + b1,
+                    0.0)
+    return jnp.einsum("bsh,bshl->bsl", h, w2, precision=F32) + b2
 
 
 def global_scores(bank: MLPBank, probs: jnp.ndarray, slot_valid: jnp.ndarray,
@@ -168,9 +176,9 @@ def init_cell_params(cell_ids: np.ndarray, n_feats: int, hidden: int,
 
 def _cell_logits_p(params: dict, feats, mu, sd) -> jnp.ndarray:
     x = (feats - mu) / sd
-    h = jnp.maximum(jnp.einsum("cqf,cfh->cqh", x, params["w1"])
+    h = jnp.maximum(jnp.einsum("cqf,cfh->cqh", x, params["w1"], precision=F32)
                     + params["b1"][:, None, :], 0.0)
-    return jnp.einsum("cqh,chl->cql", h, params["w2"]) \
+    return jnp.einsum("cqh,chl->cql", h, params["w2"], precision=F32) \
         + params["b2"][:, None, :]
 
 

@@ -8,8 +8,9 @@ suitable for batched TPU traversal:
   (§III-A1 — sibling leaves get consecutive IDs);
 * each level stores node MBRs ``[N_l, 4]`` and a ``parent`` index into the
   level above, so frontier expansion is one gather + one rect-intersection;
-* the leaf level additionally stores a padded entry tensor ``[L, M_pad, 2]``
-  (pad = +inf, so containment tests fail on padding) and the corresponding
+* the leaf level additionally stores a padded planar entry tensor
+  ``[L, 2, M_pad]`` (x row over y row; pad = +inf, so containment tests
+  fail on padding) and the corresponding
   point ids ``[L, M_pad]`` (pad = -1).
 
 All device arrays are float32/int32 — the f64 host build is only a builder.
@@ -118,7 +119,9 @@ def build_ancestor_table(level_parents, *, tl: int | None = None
 @dataclasses.dataclass(frozen=True)
 class DeviceTree:
     levels: Tuple[Level, ...]        # levels[0] has exactly 1 node (the root)
-    leaf_entries: jnp.ndarray        # [L, M_pad, 2] f32, +inf padded
+    # [L, 2, M_pad] f32, +inf padded: planar x row over y row per leaf,
+    # the [2, M] tile the refine kernels read with the entries on lanes
+    leaf_entries: jnp.ndarray
     leaf_entry_ids: jnp.ndarray      # [L, M_pad] i32, -1 padded
     leaf_counts: jnp.ndarray         # [L] i32
     n_points: int = dataclasses.field(metadata=dict(static=True))
@@ -189,7 +192,7 @@ def flatten(tree: RTree, pad_to: int | None = None,
     # ---- leaf entries, padded
     leaves = level_nodes[-1]
     L = len(leaves)
-    entries = np.full((L, M_pad, 2), np.inf, dtype=np.float32)
+    entries = np.full((L, 2, M_pad), np.inf, dtype=np.float32)
     entry_ids = np.full((L, M_pad), -1, dtype=np.int32)
     counts = np.zeros((L,), dtype=np.int32)
     for i, n in enumerate(leaves):
@@ -197,7 +200,7 @@ def flatten(tree: RTree, pad_to: int | None = None,
         k = len(ids)
         assert k <= M_pad, f"leaf fill {k} exceeds pad {M_pad}"
         if k:
-            entries[i, :k] = tree.points[ids].astype(np.float32)
+            entries[i, :, :k] = tree.points[ids].astype(np.float32).T
             entry_ids[i, :k] = np.asarray(ids, dtype=np.int32)
         counts[i] = k
 

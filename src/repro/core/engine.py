@@ -18,25 +18,12 @@ every shard" — the AI-tree's benefit scales with the mesh.
 from __future__ import annotations
 
 import dataclasses
-import functools
-import inspect
 from typing import NamedTuple, Optional
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 from jax.sharding import PartitionSpec as P
-
-try:  # jax >= 0.6: top-level export
-    _shard_map = jax.shard_map
-except AttributeError:  # older jax: experimental module
-    from jax.experimental.shard_map import shard_map as _shard_map
-# The replication-check kwarg was renamed check_rep → check_vma; detect it
-# from the signature rather than inferring from the export location (some
-# versions export jax.shard_map but still take check_rep).
-_SHARD_MAP_CHECK_KW = (
-    "check_vma" if "check_vma" in inspect.signature(_shard_map).parameters
-    else "check_rep")
 
 from repro.core.device_tree import DeviceTree, Level
 from repro.core.hybrid import HybridTree
@@ -336,8 +323,7 @@ def _ai_path(h: HybridTree, queries: jnp.ndarray, cfg: EngineConfig,
     slot table handed to ``_refine_slots``: ``topk`` builds it without
     ever materializing per-leaf scores (``_ai_slots_topk``); ``pmax``
     keeps the paper-faithful dense ``[B, L_glob]`` union and compacts the
-    local slice. ``n_model`` is the static model-axis size
-    (``jax.lax.axis_size`` is too new for the supported jax range).
+    local slice. ``n_model`` is the static model-axis size.
     """
     B = queries.shape[0]
     L_loc = h.tree.levels[-1].mbrs.shape[0]
@@ -485,17 +471,15 @@ def make_serve_step(mesh, cfg: EngineConfig, *, kind: str,
     def serve_step(h: HybridTree, queries: jnp.ndarray,
                    delta_xy: Optional[jnp.ndarray] = None) -> ServeStats:
         if delta_xy is None:
-            shard = _shard_map(
+            shard = jax.shard_map(
                 body, mesh=mesh,
                 in_specs=(tree_shardings_p(h, model_axis), qspec),
-                out_specs=ospec,
-                **{_SHARD_MAP_CHECK_KW: False})
+                out_specs=ospec, check_vma=False)
             return shard(h, queries)
-        shard = _shard_map(
+        shard = jax.shard_map(
             body_delta, mesh=mesh,
             in_specs=(tree_shardings_p(h, model_axis), qspec, P(None, None)),
-            out_specs=ospec,
-            **{_SHARD_MAP_CHECK_KW: False})
+            out_specs=ospec, check_vma=False)
         return shard(h, queries, delta_xy)
 
     return serve_step

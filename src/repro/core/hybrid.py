@@ -142,8 +142,13 @@ def hybrid_query(h: HybridTree, queries: jnp.ndarray, *,
     # the [B, L] score table exists only on the kernel-free oracle rung)
     ai = ai_query_compact(h.ait, h.tree, queries, max_results=max_results,
                           use_kernel=use_kernel)
-    r = traversal.range_query(h.tree, queries, max_visited=max_visited,
-                              max_results=max_results, use_kernel=use_kernel)
+    # serving-path R query: the traversal kernel's compaction epilogue
+    # hands the visited slots to refinement (per-field bit-identical to
+    # the dense-mask range_query)
+    r = traversal.range_query_compact(h.tree, queries,
+                                      max_visited=max_visited,
+                                      max_results=max_results,
+                                      use_kernel=use_kernel)
 
     used_ai = eligible & ~ai.fallback
     n_results = jnp.where(used_ai, ai.n_results, r.n_results)

@@ -81,8 +81,7 @@ def knn_query(tree: DeviceTree, queries: jnp.ndarray, *, k: int,
         from repro.kernels import ref as kref
         safe_idx = jnp.clip(cv.leaf_idx, 0,
                             tree.leaf_entries.shape[0] - 1)
-        d2 = kref.knn_browse(c3, tree.leaf_entries[..., 0],
-                             tree.leaf_entries[..., 1], safe_idx, cv.valid)
+        d2 = kref.knn_browse(c3, tree.leaf_entries, safe_idx, cv.valid)
     B = centers.shape[0]
     flat_d2 = d2.reshape(B, -1)                         # [B, K·M]
     safe_idx = jnp.clip(cv.leaf_idx, 0, tree.leaf_entry_ids.shape[0] - 1)

@@ -41,6 +41,7 @@ moved go stale, and the policy retrains them incrementally through
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Callable, NamedTuple, Optional
 
 import numpy as np
@@ -354,15 +355,33 @@ def _note_refit_skipped(server, d: MaintenanceDecision,
     return d._replace(refit_skipped=int(n_cells))
 
 
+@functools.partial(jax.jit, static_argnames=(
+    "max_visited", "max_results", "delta_k", "base", "use_kernel", "guard"))
+def fresh_step(hybrid: HybridTree, delta_xy: jnp.ndarray, q: jnp.ndarray, *,
+               max_visited: int, max_results: int, delta_k: int, base: int,
+               use_kernel: bool, guard: bool) -> "FreshResult":
+    """One mixed-stream batch: the hybrid query over the tree merged with
+    the delta buffer's hits. Tree, bank, guard and buffer are arguments,
+    so staging inserts or flipping guards never retraces; a repack
+    retraces once (new leaf count, new id ``base``)."""
+    res = hybrid_query(hybrid, q, max_visited=max_visited,
+                       max_results=max_results, use_kernel=use_kernel,
+                       guard=guard)
+    hits = deltalib.probe(delta_xy, q, k=delta_k, base=base,
+                          use_kernel=use_kernel)
+    merged = deltalib.merge_hybrid_result(res, hits)
+    return FreshResult(*merged, delta_hits=hits.count)
+
+
 class FreshServer:
     """Live serving state for a mixed read/write stream (single-device
     hybrid path; the distributed engine composes the same pieces via
     ``make_serve_step``'s ``delta_xy`` argument).
 
     Functionalized jax under a stateful host shell: every batch serves
-    through jit'd closures over the *current* (hybrid, delta) pair;
-    ``insert``/``repack`` swap that pair between batches, never under a
-    running step. ``serve``/``serve_wide`` realize the scheduler's
+    through the jitted ``fresh_step`` over the *current* (hybrid, delta)
+    pair; ``insert``/``repack`` swap that pair between batches, never
+    under a running step. ``serve``/``serve_wide`` realize the scheduler's
     two-tier contract (``HybridResult.truncated``), with the wide tier's
     bounds — including the delta slot bound — scaled by ``wide_factor``.
     """
@@ -402,18 +421,26 @@ class FreshServer:
 
     # -- serving -----------------------------------------------------------
 
-    def _serve(self, q: jnp.ndarray, widen: int) -> "jax.Array":
-        mv, mr = self._mv * widen, self._mr * widen
-        dk = self._dk * widen
-        res = hybrid_query(self.hybrid, q, max_visited=mv, max_results=mr,
-                           use_kernel=self._uk, guard=self._guard)
-        hits = deltalib.probe(self.delta.xy, q, k=dk, base=self.delta.base,
-                              use_kernel=self._uk)
-        merged = deltalib.merge_hybrid_result(res, hits)
-        return FreshResult(*merged, delta_hits=hits.count)
+    def _step_args(self, q, widen: int) -> tuple[tuple, dict]:
+        return (self.hybrid, self.delta.xy, jnp.asarray(q)), dict(
+            max_visited=self._mv * widen, max_results=self._mr * widen,
+            delta_k=self._dk * widen, base=self.delta.base,
+            use_kernel=self._uk, guard=self._guard)
+
+    def step(self, q: jnp.ndarray, widen: int = 1) -> "jax.Array":
+        """One batch through the current (hybrid, delta) pair, bounds
+        scaled by ``widen`` (``serve`` adds the monitor's signal feed)."""
+        args, kw = self._step_args(q, widen)
+        return fresh_step(*args, **kw)
+
+    def lower(self, q, widen: int = 1):
+        """``fresh_step`` lowered exactly as ``step`` runs it, for
+        inspecting the served program."""
+        args, kw = self._step_args(q, widen)
+        return fresh_step.lower(*args, **kw)
 
     def serve(self, q) -> "jax.Array":
-        res = self._serve(jnp.asarray(q), 1)
+        res = self.step(q)
         # narrow tier sees every query exactly once (the wide tier only
         # re-serves truncated rows) — the one place signal feeding stays
         # double-count-free
@@ -421,7 +448,7 @@ class FreshServer:
         return res
 
     def serve_wide(self, q) -> "jax.Array":
-        return self._serve(jnp.asarray(q), self._wf)
+        return self.step(q, self._wf)
 
     # -- writes ------------------------------------------------------------
 

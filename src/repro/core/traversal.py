@@ -179,8 +179,9 @@ def refine_leaves(tree: DeviceTree, queries: jnp.ndarray, leaf_idx: jnp.ndarray,
         from repro.kernels import ops as kops
         inside = kops.leaf_refine(queries, tree.leaf_entries, leaf_idx, valid)
     else:
-        pts = tree.leaf_entries[leaf_idx]                   # [B, K, M, 2]
-        inside = geo.jnp_contains_point(queries[:, None, None, :], pts)
+        pts = tree.leaf_entries[leaf_idx]                   # [B, K, 2, M]
+        inside = geo.jnp_contains_point(queries[:, None, None, :],
+                                        jnp.swapaxes(pts, -1, -2))
         inside = inside & valid[:, :, None]
     counts = jnp.sum(inside.astype(jnp.int32), axis=-1)     # [B, K]
     return RefineResult(counts=counts, inside=inside, leaf_idx=leaf_idx,

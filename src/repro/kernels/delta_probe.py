@@ -15,10 +15,10 @@ slots hold +inf coordinates, so the closed-rectangle containment test
 fails on them without the kernel ever consulting the staged count — the
 wrapper's padding and the store's capacity padding share one convention.
 
-The compaction epilogue is the shared cumsum-rank machinery from
-``traverse_fused`` (slots in buffer order = insertion order): the TPU
-form scatters via ``kc``-wide rank-equality chunks guarded by the tile's
-rank range, the interpret form binary-searches slot ranks over the
+The compaction epilogue is the shared cumsum-rank machinery of
+``kernels.epilogue`` (slots in buffer order = insertion order): the TPU
+form ranks by an MXU prefix count and scatters one in-tile rank per
+loop step, the interpret form binary-searches slot ranks over the
 tile's prefix count; both are bit-identical to
 ``compact_mask_counted(contains(q, pts), k)`` — the jnp oracle in
 ``ref.delta_probe``.
@@ -34,6 +34,7 @@ from jax.experimental import pallas as pl
 from repro.kernels.epilogue import (
     compact_epilogue_interp as _compact_epilogue_interp,
     compact_epilogue_tpu as _compact_epilogue_tpu,
+    vmem_bytes as epilogue_vmem,
 )
 from repro.kernels.traverse_fused import (COMPACT_KC, LANE,
                                           tuned_tiles_for_key)
@@ -52,8 +53,8 @@ def tuned_tiles_delta(B: int, cap: int, interp: bool) -> dict:
     return tuned_tiles_for_key(tune_key_delta(B, cap, interp))
 
 
-def vmem_estimate_delta(tb: int, tn: int, kp: int, tpu_form: bool = True,
-                        kc: int = COMPACT_KC) -> int:
+def vmem_estimate_delta(tb: int, tn: int, kp: int,
+                        tpu_form: bool = True) -> int:
     """Rough VMEM working-set bytes for one probe tile.
 
     Query tile + buffer-point tile + containment mask + the compaction
@@ -62,7 +63,7 @@ def vmem_estimate_delta(tb: int, tn: int, kp: int, tpu_form: bool = True,
     """
     est = 4 * tb * 4 + 2 * tn * 4                 # q tile, point tile
     est += tb * tn                                # containment mask
-    est += tb * tn * (kc if tpu_form else 1) * 4  # epilogue transient
+    est += epilogue_vmem(tb, tn, tpu_form)
     est += tb * (kp + 1) * 4                      # slot table + count
     return est
 
@@ -154,4 +155,5 @@ def delta_probe_t(q_t: jnp.ndarray, pts_t: jnp.ndarray, *, k: int,
         out_shape=[jax.ShapeDtypeStruct((B, kp), jnp.int32),
                    jax.ShapeDtypeStruct((B, 1), jnp.int32)],
         interpret=interpret,
+        name="delta_probe",
     )(q_t.astype(jnp.float32), pts_t.astype(jnp.float32))

@@ -9,8 +9,9 @@ threshold) pair per depth level): evaluating a tree is
     scores = onehot(leaf) @ leaf_table           (MXU matmul)
 
 The [TB, 2^D] one-hot × [2^D, C] table matmul is the hot op and maps straight
-onto the MXU. The grid is (B-tiles, T trees) with T innermost so each output
-tile accumulates tree votes in VMEM without re-fetching.
+onto the MXU. ``forest_infer`` (the router's forest) runs every tree inside
+one grid step per query tile, accumulating votes in the VMEM output tile;
+``forest_infer_cells`` grids over (B-tiles, cells, trees).
 
 Inputs:
   ``sel``    [B, T, D] f32 — pre-gathered feature values per tree/depth
@@ -31,28 +32,30 @@ from jax.experimental import pallas as pl
 DEF_TB = 256
 
 
-def _kernel(sel_ref, th_ref, tbl_ref, o_ref):
-    t = pl.program_id(1)
-    sel = sel_ref[:, 0, :]                      # [TB, D]
-    th = th_ref[0, :]                           # [D]
+def _leaf_ids(sel, th):
+    """[TB, D] features vs [1, D] thresholds → [TB, 1] i32 leaf ids
+    (bit d weighs 2^(D-1-d))."""
     D = sel.shape[-1]
-    bits = (sel > th[None, :]).astype(jnp.float32)
-    d_iota = jax.lax.broadcasted_iota(jnp.float32, (1, D), 1)
-    powers = jnp.exp2(jnp.float32(D - 1) - d_iota)          # [1, D]
-    leaf = jnp.sum(bits * powers, axis=-1).astype(jnp.int32)  # [TB]
+    powers = jnp.left_shift(
+        1, D - 1 - jax.lax.broadcasted_iota(jnp.int32, (1, D), 1))
+    return jnp.sum(jnp.where(sel > th, powers, 0), axis=-1, keepdims=True)
+
+
+def _kernel(sel_ref, th_ref, tbl_ref, o_ref):
+    # sel_ref [T, TB, D] (trees outermost, so each tree's [TB, D] slab is
+    # a tile-aligned block); th_ref [T, D]; tbl_ref [T, 2^D, C]
+    T, tb, D = sel_ref.shape
     n_leaves = tbl_ref.shape[1]
-    iota = jax.lax.broadcasted_iota(jnp.int32, (sel.shape[0], n_leaves), 1)
-    onehot = (iota == leaf[:, None]).astype(jnp.float32)               # [TB, 2^D]
-    votes = jnp.dot(onehot, tbl_ref[0, :, :],
-                    preferred_element_type=jnp.float32)                # [TB, C]
-
-    @pl.when(t == 0)
-    def _init():
-        o_ref[:, :] = votes
-
-    @pl.when(t > 0)
-    def _acc():
-        o_ref[:, :] += votes
+    iota = jax.lax.broadcasted_iota(jnp.int32, (tb, n_leaves), 1)
+    for t in range(T):
+        leaf = _leaf_ids(sel_ref[t], th_ref[t:t + 1, :])     # [TB, 1]
+        onehot = (iota == leaf).astype(jnp.float32)          # [TB, 2^D]
+        votes = jnp.dot(onehot, tbl_ref[t],
+                        preferred_element_type=jnp.float32)  # [TB, C]
+        if t == 0:
+            o_ref[:, :] = votes
+        else:
+            o_ref[:, :] += votes
 
 
 def _kernel_cells(sel_ref, th_ref, tbl_ref, o_ref):
@@ -60,15 +63,10 @@ def _kernel_cells(sel_ref, th_ref, tbl_ref, o_ref):
     per cell — tree votes accumulate within a cell, not across cells."""
     t = pl.program_id(2)
     sel = sel_ref[:, 0, :]
-    th = th_ref[0, :]
-    D = sel.shape[-1]
-    bits = (sel > th[None, :]).astype(jnp.float32)
-    d_iota = jax.lax.broadcasted_iota(jnp.float32, (1, D), 1)
-    powers = jnp.exp2(jnp.float32(D - 1) - d_iota)
-    leaf = jnp.sum(bits * powers, axis=-1).astype(jnp.int32)
+    leaf = _leaf_ids(sel, th_ref[0:1, :])
     n_leaves = tbl_ref.shape[1]
     iota = jax.lax.broadcasted_iota(jnp.int32, (sel.shape[0], n_leaves), 1)
-    onehot = (iota == leaf[:, None]).astype(jnp.float32)
+    onehot = (iota == leaf).astype(jnp.float32)
     votes = jnp.dot(onehot, tbl_ref[0, :, :],
                     preferred_element_type=jnp.float32)
 
@@ -111,22 +109,27 @@ def forest_infer_cells(sel: jnp.ndarray, thresh: jnp.ndarray,
 @functools.partial(jax.jit, static_argnames=("tb", "interpret"))
 def forest_infer(sel: jnp.ndarray, thresh: jnp.ndarray, tables: jnp.ndarray,
                  *, tb: int = DEF_TB, interpret: bool = False) -> jnp.ndarray:
-    """sel [B,T,D], thresh [T,D], tables [T,2^D,C] → scores [B,C]."""
+    """sel [B,T,D], thresh [T,D], tables [T,2^D,C] → scores [B,C].
+
+    One grid step per query tile runs every tree (forests are small: the
+    router's is 16 trees of depth 6), so the thresholds and vote tables
+    are whole blocks and the features arrive tree-major.
+    """
     B, T, D = sel.shape
     T2, n_leaves, C = tables.shape
     assert T2 == T and n_leaves == 2 ** D, (tables.shape, D)
     assert B % tb == 0, (B, tb)
-    grid = (B // tb, T)
     return pl.pallas_call(
         _kernel,
-        grid=grid,
+        grid=(B // tb,),
         in_specs=[
-            pl.BlockSpec((tb, 1, D), lambda b, t: (b, t, 0)),
-            pl.BlockSpec((1, D), lambda b, t: (t, 0)),
-            pl.BlockSpec((1, n_leaves, C), lambda b, t: (t, 0, 0)),
+            pl.BlockSpec((T, tb, D), lambda b: (0, b, 0)),
+            pl.BlockSpec((T, D), lambda b: (0, 0)),
+            pl.BlockSpec((T, n_leaves, C), lambda b: (0, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((tb, C), lambda b, t: (b, 0)),
+        out_specs=pl.BlockSpec((tb, C), lambda b: (b, 0)),
         out_shape=jax.ShapeDtypeStruct((B, C), jnp.float32),
         interpret=interpret,
-    )(sel.astype(jnp.float32), thresh.astype(jnp.float32),
-      tables.astype(jnp.float32))
+        name="forest_infer",
+    )(jnp.transpose(sel.astype(jnp.float32), (1, 0, 2)),
+      thresh.astype(jnp.float32), tables.astype(jnp.float32))

@@ -15,7 +15,8 @@ never exists on this path — the slot table is the only interchange.
 Two grid forms, one semantics (the ``leaf_refine`` split):
 
 * ``fold_k=False`` (the TPU form): a ``(B, K)`` grid, one cell per
-  (query, leaf slot), each DMA-ing one named ``[1, M]`` leaf tile.
+  (query, leaf slot), each DMA-ing one named leaf's ``[2, M]`` entry
+  tile — ``leaf_refine``'s ``slot_grid_call``, with the centers in SMEM.
 * ``fold_k=True`` (the interpret form): the grid folds away — an XLA
   gather stages the ``[B, K, M]`` slab and the kernel body runs once.
   Bit-identical outputs; the right trade when the "DMA" is an emulated
@@ -23,7 +24,7 @@ Two grid forms, one semantics (the ``leaf_refine`` split):
 
 Inputs (planar entry layout):
   ``centers``  [B, 3] f32   — query center x, center y, radius²
-  ``ex``/``ey``[L, M] f32   — entry coordinates, +inf padded
+  ``entries``  [L, 2, M] f32 — x row over y row per leaf, +inf padded
   ``leaf_idx`` [B, K] i32   — leaves to browse (scalar-prefetched)
   ``valid``    [B, K] i32   — slot validity
 Output:
@@ -40,8 +41,8 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
-from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.leaf_refine import slot_grid_call
 from repro.kernels.traverse_fused import tuned_tiles_for_key
 
 
@@ -58,25 +59,30 @@ def tuned_tiles_knn(B: int, K: int, M: int, interp: bool) -> dict:
 def vmem_estimate_knn(B: int, K: int, M: int, tpu_form: bool = True) -> int:
     """Rough VMEM working-set bytes for one browse dispatch.
 
-    The TPU form's cell working set is one query row + one entry tile +
-    one output tile; the folded form stages the whole gathered
-    ``[B, K, M]`` slab (gx, gy, out) plus the query/valid blocks.
+    The TPU form's cell working set is one double-buffered entry tile +
+    the query's double-buffered ``[K, M]`` output row; the folded form
+    stages the whole gathered ``[B, K, M]`` slab (gx, gy, out) plus the
+    query/valid blocks.
     """
     if tpu_form:
-        return 3 * 4 + 4 + 2 * M * 4 + M * 4
+        return 2 * (2 * M * 4) + 2 * K * M * 4
     return B * (3 + K) * 4 + 3 * B * K * M * 4
 
 
-def _kernel(idx_ref, q_ref, valid_ref, ex_ref, ey_ref, o_ref):
-    # q_ref: [1, 3]; ex/ey_ref: [1, M]; valid_ref: [1, 1]; o_ref: [1, 1, M]
-    cx = q_ref[0, 0]
-    cy = q_ref[0, 1]
-    r2 = q_ref[0, 2]
-    dx = ex_ref[0, :] - cx
-    dy = ey_ref[0, :] - cy
-    d2 = dx * dx + dy * dy
-    ok = (d2 <= r2) & (valid_ref[0, 0] > 0)
-    o_ref[0, 0, :] = jnp.where(ok, d2, jnp.inf)
+def _kernel(idx_ref, q_ref, e_ref, o_ref, *, K: int, Q: int):
+    # q_ref: flat [B·3] (cx, cy, r²) in SMEM; e_ref: [2, M]; o_ref: [K, M]
+    b = pl.program_id(0)
+    k = pl.program_id(1)
+    cx = q_ref[Q * b]
+    cy = q_ref[Q * b + 1]
+    r2 = q_ref[Q * b + 2]
+    e = e_ref[:, :]
+    c = jnp.where(jax.lax.broadcasted_iota(jnp.int32, e.shape, 0) == 0,
+                  cx, cy)
+    sq = (e - c) * (e - c)             # rows dx², dy²
+    d2 = sq[0:1, :] + sq[1:2, :]
+    ok = (d2 <= r2) & (idx_ref[b * K + k] >= 0)
+    o_ref[pl.ds(k, 1), :] = jnp.where(ok, d2, jnp.inf)
 
 
 def _kernel_folded(q_ref, valid_ref, gx_ref, gy_ref, o_ref):
@@ -93,11 +99,11 @@ def _kernel_folded(q_ref, valid_ref, gx_ref, gy_ref, o_ref):
 
 
 @functools.partial(jax.jit, static_argnames=("interpret", "fold_k"))
-def knn_browse(centers: jnp.ndarray, ex: jnp.ndarray, ey: jnp.ndarray,
+def knn_browse(centers: jnp.ndarray, entries: jnp.ndarray,
                leaf_idx: jnp.ndarray, valid: jnp.ndarray, *,
                interpret: bool = False,
                fold_k: bool | None = None) -> jnp.ndarray:
-    """centers [B,3] (cx,cy,r²), ex/ey [L,M], leaf_idx/valid [B,K]
+    """centers [B,3] (cx,cy,r²), entries [L,2,M], leaf_idx/valid [B,K]
     → d2 [B,K,M] f32 (+inf where masked).
 
     ``fold_k`` defaults to ``interpret``: the (B, K) scalar-prefetch grid
@@ -107,32 +113,15 @@ def knn_browse(centers: jnp.ndarray, ex: jnp.ndarray, ey: jnp.ndarray,
     if fold_k is None:
         fold_k = interpret
     B, K = leaf_idx.shape
-    L, M = ex.shape
+    M = entries.shape[2]
     if fold_k:
-        gx = ex[leaf_idx]                       # [B, K, M] XLA-level gather
-        gy = ey[leaf_idx]
+        g = entries[leaf_idx]                   # [B, K, 2, M] XLA gather
+        gx, gy = g[:, :, 0], g[:, :, 1]
         return pl.pallas_call(
             _kernel_folded,
             out_shape=jax.ShapeDtypeStruct((B, K, M), jnp.float32),
             interpret=interpret,
         )(centers.astype(jnp.float32), valid.astype(jnp.int32),
           gx.astype(jnp.float32), gy.astype(jnp.float32))
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=1,
-        grid=(B, K),
-        in_specs=[
-            pl.BlockSpec((1, 3), lambda b, k, idx: (b, 0)),
-            pl.BlockSpec((1, 1), lambda b, k, idx: (b, k)),
-            pl.BlockSpec((1, M), lambda b, k, idx: (idx[b, k], 0)),
-            pl.BlockSpec((1, M), lambda b, k, idx: (idx[b, k], 0)),
-        ],
-        out_specs=pl.BlockSpec((1, 1, M), lambda b, k, idx: (b, k, 0)),
-    )
-    return pl.pallas_call(
-        _kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((B, K, M), jnp.float32),
-        interpret=interpret,
-    )(leaf_idx.astype(jnp.int32), centers.astype(jnp.float32),
-      valid.astype(jnp.int32), ex.astype(jnp.float32),
-      ey.astype(jnp.float32))
+    return slot_grid_call(_kernel, "knn_browse", centers, entries,
+                          leaf_idx, valid, jnp.float32, interpret)

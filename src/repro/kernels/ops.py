@@ -3,14 +3,17 @@
 Responsibilities:
   * pad irregular shapes up to kernel tile multiples and slice results back;
   * transpose rectangles to the planar [4, N] kernel layout;
-  * dispatch to interpret mode off-TPU (this container is CPU-only — the
-    kernels are *targeted* at TPU and *validated* via interpret mode);
-  * fall back to the jnp oracle when ``REPRO_KERNELS=off`` (escape hatch).
+  * compile the kernels with Mosaic on a TPU, and run them in interpret
+    mode on any other backend (the CPU test reference);
+  * fall back to the jnp oracle when ``REPRO_KERNELS=off`` (a CPU test
+    reference); a VMEM-gate fallback to the oracle exists in interpret
+    mode only — on a TPU an over-budget shape raises.
 """
 from __future__ import annotations
 
 import functools
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -34,6 +37,17 @@ def kernels_enabled() -> bool:
 
 def _interpret() -> bool:
     return jax.default_backend() != "tpu"
+
+
+def mosaic_kernels(compiled_text: str) -> set:
+    """Names of the Pallas kernels a compiled program calls as Mosaic
+    custom calls (``tpu_custom_call``), read from each call's
+    ``op_name`` (every kernel's ``pallas_call`` is named)."""
+    names = set()
+    for line in compiled_text.splitlines():
+        if "tpu_custom_call" in line:
+            names.update(re.findall(r'/(\w+)/pallas_call"', line))
+    return names
 
 
 def _pad_to(x: jnp.ndarray, axis: int, mult: int, value) -> jnp.ndarray:
@@ -225,7 +239,7 @@ def _sliced_call(queries: jnp.ndarray, level_mbrs, level_parents, sl,
         kp = k if interp else \
             (k + _traverse.LANE - 1) // _traverse.LANE * _traverse.LANE
         est = _traverse.vmem_estimate_sliced_compact(
-            sl.widths, tb, sl.tl, kp, tpu_form=not interp, kc=kc)
+            sl.widths, tb, sl.tl, kp, tpu_form=not interp)
     if est > _traverse.VMEM_BUDGET:
         return None
     qp, int_mbrs_t, int_parents, leaf_mt, leaf_pt = _sliced_operands(
@@ -337,7 +351,7 @@ def traverse_compact(queries: jnp.ndarray, level_mbrs, level_parents,
     widths = [int(m.shape[0]) for m in level_mbrs[:-1]]
     padded = [n + (-n) % _traverse.LANE for n in widths]
     if _traverse.vmem_estimate_compact(padded, tb, tl, kp,
-                                       tpu_form=not interp, kc=kc) > \
+                                       tpu_form=not interp) > \
             _traverse.VMEM_BUDGET:
         sl = slices if _slices_usable(slices, n_levels, L) else \
             _build_slices_if_concrete(level_parents, B, L, n_levels,
@@ -362,22 +376,50 @@ def traverse_compact(queries: jnp.ndarray, level_mbrs, level_parents,
     return jnp.where(valid, idx[:B, :k], 0), valid, count
 
 
-def _mlp_tiles(B: int, n_leaves: int, C: int, Cl: int, interp: bool,
-               tb: int | None = None, tl: int | None = None
-               ) -> tuple[int, int, int, int]:
+def _mlp_vmem(bank, S: int, k: int, interp: bool, tb: int, tl: int,
+              n_cells: int | None = None) -> int:
+    """``vmem_estimate_mlp`` at the padded cell and label-slot counts the
+    kernel really sees (as ``mlp_predict_compact`` pads them)."""
+    C, F, H = bank.w1.shape
+    C = n_cells or C
+    Cl = bank.w2.shape[-1]
+    kp = k if interp else \
+        (k + _traverse.LANE - 1) // _traverse.LANE * _traverse.LANE
+    Cp = C + (-C) % _mlp.CELL_BLOCK
+    Clp = Cl if interp else Cl + (-Cl) % _traverse.LANE
+    return _mlp.vmem_estimate_mlp(Cp, F, H, Clp, S, tb, tl, kp,
+                                  tpu_form=not interp)
+
+
+def _mlp_tiles(B: int, n_leaves: int, bank, S: int, k: int, interp: bool,
+               tb: int | None = None, tl: int | None = None,
+               n_cells: int | None = None) -> tuple[int, int, int, int]:
     """Tile resolution for the fused prediction kernel: explicit caller
     override → autotune cache entry (``mlp-`` form keys) → hand-picked
     default. Returns ``(tb, tl, kc, Lp)`` with ``Lp`` the lane-padded
-    leaf count (the kernel's scatter axis)."""
-    tune = _mlp.tuned_tiles_mlp(B, n_leaves, C, Cl, interp)
+    leaf count (the kernel's scatter axis).
+
+    TPU form: a default query tile over the VMEM budget is halved while
+    it stays a multiple of the lane quantum — the inference stage's
+    candidate list (``[tb, S·Cl]``) grows with the bank's label slots."""
+    C = n_cells or bank.w1.shape[0]
+    tune = _mlp.tuned_tiles_mlp(B, n_leaves, C, bank.w2.shape[-1], interp)
     Lp = (max(128, n_leaves) + 127) // 128 * 128
-    if tb is None:
-        tb = tune.get("tb") or min(1024 if interp else _mlp.DEF_TB,
-                                   (max(8, B) + 7) // 8 * 8)
     if tl is None:
         # interpret folds the whole (lane-padded) leaf axis into one tile —
         # emulated grid cells are not free and the walk has no scratch there
         tl = tune.get("tl") or (Lp if interp else min(_mlp.DEF_TL, Lp))
+    if tb is None:
+        tb = tune.get("tb") or min(1024 if interp else _mlp.DEF_TB,
+                                   (max(8, B) + 7) // 8 * 8)
+        if not interp and tb < B:
+            # the union stage blocks the transposed candidate list
+            # [S·Cl, B] by tb lanes
+            tb = max(_traverse.LANE, tb // _traverse.LANE * _traverse.LANE)
+        while (not interp and tb % (2 * _traverse.LANE) == 0
+               and _mlp_vmem(bank, S, k, interp, tb, tl, n_cells)
+               > _traverse.VMEM_BUDGET):
+            tb //= 2
     kc = tune.get("kc", _traverse.COMPACT_KC)
     return tb, tl, kc, Lp
 
@@ -386,22 +428,12 @@ def _mlp_gate(B: int, bank, S: int, n_leaves: int, k: int,
               tb: int | None = None, tl: int | None = None,
               n_cells: int | None = None) -> bool:
     """True iff the resolved fused-kernel form fits the VMEM budget.
-
-    The estimate uses the *lane-padded* cell count — the kernel's
-    replicated bank operands are padded to the LANE quantum, and the pad
-    rows cost VMEM like any others (the sibling ``traverse_compact`` gate
-    pads its level widths for the same reason). ``n_cells`` overrides the
-    bank's cell count for callers asking about a *shard* of the bank."""
-    C, F, H = bank.w1.shape
-    C = n_cells or C
-    Cl = bank.w2.shape[-1]
+    ``n_cells`` overrides the bank's cell count for callers asking about
+    a *shard* of the bank."""
     interp = _interpret()
-    tb, tl, kc, _ = _mlp_tiles(B, n_leaves, C, Cl, interp, tb, tl)
-    kp = k if interp else \
-        (k + _traverse.LANE - 1) // _traverse.LANE * _traverse.LANE
-    Cp = C + (-C) % _traverse.LANE
-    return _mlp.vmem_estimate_mlp(Cp, F, H, Cl, S, tb, tl, kp,
-                                  tpu_form=not interp, kc=kc) \
+    tb, tl, _, _ = _mlp_tiles(B, n_leaves, bank, S, k, interp, tb, tl,
+                              n_cells)
+    return _mlp_vmem(bank, S, k, interp, tb, tl, n_cells) \
         <= _traverse.VMEM_BUDGET
 
 
@@ -417,6 +449,17 @@ def mlp_fused_active(B: int, bank, S: int, n_leaves: int, k: int,
                                            n_cells=n_cells)
 
 
+def _oracle_rung(kernel: str) -> None:
+    """Gate for the VMEM fallback rungs: in interpret mode the dense
+    oracle stands in (bit-identical); on a TPU an over-budget shape is a
+    tiling fault to fix, never a silent drop off the device's kernels."""
+    if not _interpret():
+        raise ValueError(
+            f"{kernel}: shape exceeds the kernel's VMEM budget "
+            f"({_traverse.VMEM_BUDGET} bytes); refusing the jnp-oracle "
+            f"fallback on TPU")
+
+
 def mlp_predict_compact(queries: jnp.ndarray, bank, cell_ids: jnp.ndarray,
                         slot_ok: jnp.ndarray, *, n_leaves: int, k: int,
                         threshold: float, tb: int | None = None,
@@ -428,59 +471,56 @@ def mlp_predict_compact(queries: jnp.ndarray, bank, cell_ids: jnp.ndarray,
 
     Semantically ``compact_mask_counted(predict_scores(...) > threshold,
     k)``, but on the kernel path the ``[B, n_leaves]`` score table never
-    leaves VMEM: classifier inference, sigmoid+threshold, the
-    ``label_map`` scatter/max-union and the cumsum-rank compaction all run
-    inside one ``pallas_call`` (``kernels.mlp_infer``). ``bank`` is an
-    ``MLPBank``-shaped object (``w1/b1/w2/b2/mu/sd/label_map/lmask`` —
-    duck-typed so this module stays core-free); ``cell_ids``/``slot_ok``
-    [B, S] come from ``grid.cells_of_queries``. Requires ``threshold ≥ 0``
-    (see ``mlp_infer`` module docs).
+    exists: classifier inference, sigmoid+threshold, the ``label_map``
+    union and the cumsum-rank compaction all run in ``kernels.mlp_infer``.
+    ``bank`` is an ``MLPBank``-shaped object
+    (``w1/b1/w2/b2/mu/sd/label_map/lmask`` — duck-typed so this module
+    stays core-free); ``cell_ids``/``slot_ok`` [B, S] come from
+    ``grid.cells_of_queries``. Requires ``threshold ≥ 0`` (see
+    ``mlp_infer`` module docs).
 
     Fallback ladder mirrors ``traverse_compact``: the jnp dense oracle
-    when kernels are off **or** when the form-aware VMEM estimate (bank
-    operands + staging transients + epilogue transient) exceeds the
-    budget — never a silent wrong answer, the fallbacks are bit-identical.
-    Tile knobs resolve explicit override → autotune cache entry for this
-    (form, B, L, C, Cl) shape → hand-picked default.
+    when kernels are off, and in interpret mode when the form-aware VMEM
+    estimate exceeds the budget (bit-identical); on a TPU an over-budget
+    shape raises. Tile knobs resolve explicit override → autotune cache
+    entry for this (form, B, L, C, Cl) shape → hand-picked default.
     """
     assert threshold >= 0, "dense-oracle parity requires threshold >= 0"
     B = queries.shape[0]
     S = cell_ids.shape[1]
-    C, F, H = bank.w1.shape
-    Cl = bank.w2.shape[-1]
+    C = bank.w1.shape[0]
     x = (queries.astype(jnp.float32) - bank.mu) / bank.sd
     cid = jnp.clip(cell_ids.astype(jnp.int32), 0, C - 1)
 
-    def dense():
+    if not kernels_enabled() or not _mlp_gate(B, bank, S, n_leaves, k,
+                                              tb, tl):
+        if kernels_enabled():
+            _oracle_rung("mlp_predict_compact")
         return ref.mlp_predict_compact(
             x, cid, slot_ok, bank.w1, bank.b1, bank.w2, bank.b2,
             bank.label_map, bank.lmask, n_leaves=n_leaves, k=k,
             threshold=threshold)
-
-    if not kernels_enabled() or not _mlp_gate(B, bank, S, n_leaves, k,
-                                              tb, tl):
-        return dense()
     interp = _interpret()
-    tb, tl, kc, Lp = _mlp_tiles(B, n_leaves, C, Cl, interp, tb, tl)
+    tb, tl, kc, Lp = _mlp_tiles(B, n_leaves, bank, S, k, interp, tb, tl)
     xp = _pad_to(x, 0, tb, 0.0)
     cidp = _pad_to(cid, 0, tb, 0)
     okp = _pad_to(slot_ok.astype(jnp.int32), 0, tb, 0)
-    Cp = (-C) % _traverse.LANE
-    w1f = bank.w1.reshape(C, F * H)
-    w2f = bank.w2.reshape(C, H * Cl)
-    b1a, b2a = bank.b1, bank.b2
-    lm = bank.label_map.astype(jnp.float32)
-    lmk = bank.lmask.astype(jnp.float32)
-    if Cp:
-        w1f = _pad_to(w1f, 0, _traverse.LANE, 0.0)
-        w2f = _pad_to(w2f, 0, _traverse.LANE, 0.0)
-        b1a = _pad_to(b1a, 0, _traverse.LANE, 0.0)
-        b2a = _pad_to(b2a, 0, _traverse.LANE, 0.0)
-        lm = _pad_to(lm, 0, _traverse.LANE, -1.0)
-        lmk = _pad_to(lmk, 0, _traverse.LANE, 0.0)
+    # label slots that predict nothing fold into the map as -1; padding
+    # cells are never routed to (ids are clipped below C), padding label
+    # slots carry -1
+    lm = jnp.where(bank.lmask, bank.label_map, -1).astype(jnp.int32)
+    w1, b1, w2, b2 = bank.w1, bank.b1, bank.w2, bank.b2
+    cell_mult = _mlp.CELL_BLOCK
+    if not interp:
+        w2 = _pad_to(w2, 2, _traverse.LANE, 0.0)
+        b2 = _pad_to(b2, 1, _traverse.LANE, 0.0)
+        lm = _pad_to(lm, 1, _traverse.LANE, -1)
+    w1, b1, w2, b2 = (_pad_to(a, 0, cell_mult, 0.0) for a in (w1, b1, w2,
+                                                               b2))
+    lm = _pad_to(lm, 0, cell_mult, -1)
     lpt = Lp + (-Lp) % tl
     idx, cnt = _mlp.mlp_predict_compact_t(
-        xp, cidp, okp, w1f, b1a, w2f, b2a, lm, lmk, k=k, lp=lpt,
+        xp, cidp, okp, w1, b1, w2, b2, lm, k=k, lp=lpt,
         thr=float(threshold), tb=tb, tl=tl, kc=kc, interpret=interp)
     count = cnt[:B, 0]
     valid = jnp.arange(k, dtype=jnp.int32)[None, :] < count[:, None]
@@ -532,8 +572,9 @@ def delta_probe(queries: jnp.ndarray, pts: jnp.ndarray, *, k: int,
     tb, tn, kc = _delta_tiles(B, cap, interp, tb, tn)
     kp = k if interp else \
         (k + _traverse.LANE - 1) // _traverse.LANE * _traverse.LANE
-    if _delta.vmem_estimate_delta(tb, tn, kp, tpu_form=not interp,
-                                  kc=kc) > _traverse.VMEM_BUDGET:
+    if _delta.vmem_estimate_delta(tb, tn, kp, tpu_form=not interp) > \
+            _traverse.VMEM_BUDGET:
+        _oracle_rung("delta_probe")
         return ref.delta_probe(queries, pts, k)
     qp = _pad_to(queries.astype(jnp.float32), 0, tb, 0.0)
     pp = _pad_to(pts.astype(jnp.float32), 0, tn, jnp.inf)
@@ -576,49 +617,48 @@ def spatial_key(queries: jnp.ndarray, bbox: jnp.ndarray | None = None,
 
 def leaf_refine(queries: jnp.ndarray, leaf_entries: jnp.ndarray,
                 leaf_idx: jnp.ndarray, valid: jnp.ndarray) -> jnp.ndarray:
-    """queries [B,4], leaf_entries [L,M,2], leaf_idx [B,K], valid [B,K]
+    """queries [B,4], leaf_entries [L,2,M], leaf_idx [B,K], valid [B,K]
     → inside [B, K, M] bool."""
-    ex = leaf_entries[..., 0]
-    ey = leaf_entries[..., 1]
     if not kernels_enabled():
-        return ref.leaf_refine(queries, ex, ey, leaf_idx, valid)
+        return ref.leaf_refine(queries, leaf_entries, leaf_idx, valid)
     # clamp padded slots to leaf 0 (masked out by ``valid`` in-kernel)
-    safe_idx = jnp.clip(leaf_idx, 0, ex.shape[0] - 1)
-    return _refine.leaf_refine(queries, ex, ey, safe_idx, valid,
+    safe_idx = jnp.clip(leaf_idx, 0, leaf_entries.shape[0] - 1)
+    return _refine.leaf_refine(queries, leaf_entries, safe_idx, valid,
                                interpret=_interpret())
 
 
 def knn_browse(centers: jnp.ndarray, leaf_entries: jnp.ndarray,
                leaf_idx: jnp.ndarray, valid: jnp.ndarray) -> jnp.ndarray:
     """Distance-browse compact visited-leaf slots: centers [B, 3]
-    (cx, cy, r²), leaf_entries [L, M, 2], leaf_idx/valid [B, K]
+    (cx, cy, r²), leaf_entries [L, 2, M], leaf_idx/valid [B, K]
     → d2 [B, K, M] f32 (+inf where masked).
 
     The kNN serving primitive: only the leaves named in the slot table
     are touched (scalar-prefetched tiles on the TPU form, an XLA gather
     on the folded interpret form — see ``kernels.knn_browse``); the
     caller's top-k over the flat ``[B, K·M]`` view yields the k nearest
-    within the probed radius. Fallback ladder mirrors ``leaf_refine``:
-    the jnp oracle when kernels are off or the form-aware VMEM estimate
-    exceeds the budget — bit-identical either way. The autotune cache is
-    consulted under ``knn-*`` keys for a pinned form (``fold_k``).
+    within the probed radius. Fallback ladder mirrors
+    ``mlp_predict_compact``: the jnp oracle when kernels are off, and in
+    interpret mode when the form-aware VMEM estimate exceeds the budget —
+    bit-identical either way; on a TPU an over-budget shape raises. The
+    autotune cache is consulted under ``knn-*`` keys for a pinned form
+    (``fold_k``).
     """
-    ex = leaf_entries[..., 0]
-    ey = leaf_entries[..., 1]
     if not kernels_enabled():
-        return ref.knn_browse(centers, ex, ey, leaf_idx, valid)
+        return ref.knn_browse(centers, leaf_entries, leaf_idx, valid)
     interp = _interpret()
     B, K = leaf_idx.shape
-    M = ex.shape[1]
+    M = leaf_entries.shape[2]
     tune = _knn.tuned_tiles_knn(B, K, M, interp)
     fold = tune.get("fold_k")
     fold = interp if fold is None else bool(fold)
     if _knn.vmem_estimate_knn(B, K, M, tpu_form=not fold) > \
             _traverse.VMEM_BUDGET:
-        return ref.knn_browse(centers, ex, ey, leaf_idx, valid)
+        _oracle_rung("knn_browse")
+        return ref.knn_browse(centers, leaf_entries, leaf_idx, valid)
     # clamp padded slots to leaf 0 (masked out by ``valid`` in-kernel)
-    safe_idx = jnp.clip(leaf_idx, 0, ex.shape[0] - 1)
-    return _knn.knn_browse(centers, ex, ey, safe_idx, valid,
+    safe_idx = jnp.clip(leaf_idx, 0, leaf_entries.shape[0] - 1)
+    return _knn.knn_browse(centers, leaf_entries, safe_idx, valid,
                            interpret=interp, fold_k=fold)
 
 

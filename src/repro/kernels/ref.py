@@ -138,9 +138,12 @@ def mlp_predict_scores(x: jnp.ndarray, cell_ids: jnp.ndarray,
     b1g = b1[cell_ids]
     w2g = w2[cell_ids]                              # [B, S, H, Cl]
     b2g = b2[cell_ids]
+    hi = jax.lax.Precision.HIGHEST          # the bank's f32 contract
     h = jnp.maximum(
-        jnp.einsum("bf,bsfh->bsh", x.astype(jnp.float32), w1g) + b1g, 0.0)
-    probs = jax.nn.sigmoid(jnp.einsum("bsh,bshl->bsl", h, w2g) + b2g)
+        jnp.einsum("bf,bsfh->bsh", x.astype(jnp.float32), w1g,
+                   precision=hi) + b1g, 0.0)
+    probs = jax.nn.sigmoid(
+        jnp.einsum("bsh,bshl->bsl", h, w2g, precision=hi) + b2g)
     lm = label_map[cell_ids]                        # [B, S, Cl]
     ok = slot_ok[:, :, None] & lmask[cell_ids]
     tgt = jnp.where(ok, lm, n_leaves)               # park invalid at L
@@ -192,20 +195,21 @@ def delta_probe(queries: jnp.ndarray, pts: jnp.ndarray, k: int
     return compact_mask_counted(delta_contains(queries, pts), k)
 
 
-def leaf_refine(queries: jnp.ndarray, ex: jnp.ndarray, ey: jnp.ndarray,
+def leaf_refine(queries: jnp.ndarray, entries: jnp.ndarray,
                 leaf_idx: jnp.ndarray, valid: jnp.ndarray) -> jnp.ndarray:
-    """queries [B,4], ex/ey [L,M], leaf_idx [B,K], valid [B,K] → [B,K,M]."""
-    gx = ex[leaf_idx].astype(jnp.float32)       # [B, K, M]
-    gy = ey[leaf_idx].astype(jnp.float32)
+    """queries [B,4], entries [L,2,M], leaf_idx [B,K], valid [B,K]
+    → [B,K,M]."""
+    g = entries[leaf_idx].astype(jnp.float32)   # [B, K, 2, M]
+    gx, gy = g[:, :, 0], g[:, :, 1]
     q = queries.astype(jnp.float32)
     x0, y0, x1, y1 = (q[:, i][:, None, None] for i in range(4))
     ok = (gx >= x0) & (gx <= x1) & (gy >= y0) & (gy <= y1)
     return ok & (valid[:, :, None] > 0)
 
 
-def knn_browse(centers: jnp.ndarray, ex: jnp.ndarray, ey: jnp.ndarray,
+def knn_browse(centers: jnp.ndarray, entries: jnp.ndarray,
                leaf_idx: jnp.ndarray, valid: jnp.ndarray) -> jnp.ndarray:
-    """centers [B,3] (cx,cy,r²), ex/ey [L,M], leaf_idx/valid [B,K]
+    """centers [B,3] (cx,cy,r²), entries [L,2,M], leaf_idx/valid [B,K]
     → d2 [B,K,M] f32 (+inf outside the radius / invalid slots).
 
     Ground truth for ``kernels.knn_browse``: squared Euclidean distance
@@ -215,8 +219,8 @@ def knn_browse(centers: jnp.ndarray, ex: jnp.ndarray, ey: jnp.ndarray,
     the identical term order (dx·dx + dy·dy) keeps the kernel twin
     bit-exact.
     """
-    gx = ex[leaf_idx].astype(jnp.float32)       # [B, K, M]
-    gy = ey[leaf_idx].astype(jnp.float32)
+    g = entries[leaf_idx].astype(jnp.float32)   # [B, K, 2, M]
+    gx, gy = g[:, :, 0], g[:, :, 1]
     q = centers.astype(jnp.float32)
     cx = q[:, 0][:, None, None]
     cy = q[:, 1][:, None, None]

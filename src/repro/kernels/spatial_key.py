@@ -109,4 +109,5 @@ def spatial_key_t(cxy_t: jnp.ndarray, *, curve: str = "hilbert",
         out_specs=pl.BlockSpec((1, tb), lambda i: (0, i)),
         out_shape=jax.ShapeDtypeStruct((1, B), jnp.int32),
         interpret=interpret,
+        name="spatial_key",
     )(cxy_t.astype(jnp.float32))

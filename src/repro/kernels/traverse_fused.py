@@ -56,14 +56,22 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+# The compaction epilogues live in ``kernels.epilogue`` (shared with
+# ``mlp_infer`` and ``delta_probe``); the private names stay importable
+# here.
+from repro.kernels.epilogue import (
+    compact_epilogue_interp as _compact_epilogue_interp,
+    compact_epilogue_tpu as _compact_epilogue_tpu,
+    vmem_bytes as epilogue_vmem,
+)
+
 
 DEF_TB = 256    # query-tile (sublane axis)
 DEF_TL = 512    # leaf-tile (lane axis, multiple of 128)
 SUB_TL = 512    # interpret-form early-exit subtile within the leaf tile
 LANE = 128      # internal-level width quantum
-# Slot-chunk width for the TPU-form compaction epilogue: the rank-equality
-# scatter materializes a [TB, TL, COMPACT_KC] compare per chunk, so the
-# chunk width bounds that transient (counted by vmem_estimate_compact).
+# In-tile ranks per loop step of the TPU-form compaction epilogue (the
+# loop's unroll; ``kernels.epilogue``).
 COMPACT_KC = 8
 # VMEM budget (bytes) for the TPU-form kernel's resident working set —
 # frontier scratch, replicated internal-level operands, and the largest
@@ -176,24 +184,22 @@ def vmem_estimate(int_widths_padded: Sequence[int], tb: int, tl: int) -> int:
 
 
 def vmem_estimate_compact(int_widths_padded: Sequence[int], tb: int, tl: int,
-                          kp: int, tpu_form: bool = True,
-                          kc: int = COMPACT_KC) -> int:
+                          kp: int, tpu_form: bool = True) -> int:
     """VMEM working-set bytes for the fused traversal+compaction kernel.
 
     The walk terms match ``vmem_estimate``; the compaction epilogue swaps
     the [tb, tl] mask output tile for the [tb, kp] slot table + [tb, 1]
-    count, and adds the largest epilogue transient. That transient is
-    form-dependent: the TPU form's chunked rank-equality scatter
-    materializes a [tb, tl, COMPACT_KC] compare, while the interpret form's
-    binary search only needs the [tb, tl] prefix-count — gating the
-    interpret run (whose ``tl`` is the whole folded leaf axis) on the TPU
-    chunk transient would spuriously push CPU runs onto the per-level
-    fallback.
+    count, and adds the epilogue transient. That transient is
+    form-dependent (``epilogue.vmem_bytes``): the TPU form's prefix
+    matmul stages a [tl, tl] triangle, while the interpret form's binary
+    search only needs the [tb, tl] prefix count — gating the interpret
+    run (whose ``tl`` is the whole folded leaf axis) on the TPU transient
+    would spuriously push CPU runs onto the per-level fallback.
     """
     est = vmem_estimate(int_widths_padded, tb, tl)
     est -= tb * tl                          # no [tb, tl] bool output tile
     est += tb * (kp + 1) * 4                # slot table + count accumulators
-    est += tb * tl * (kc if tpu_form else 1) * 4  # epilogue transient
+    est += epilogue_vmem(tb, tl, tpu_form)
     return est
 
 
@@ -224,15 +230,14 @@ def vmem_estimate_sliced(widths: Sequence[int], tb: int, tl: int,
 
 
 def vmem_estimate_sliced_compact(widths: Sequence[int], tb: int, tl: int,
-                                 kp: int, tpu_form: bool = True,
-                                 kc: int = COMPACT_KC) -> int:
+                                 kp: int, tpu_form: bool = True) -> int:
     """Sliced-walk analogue of ``vmem_estimate_compact``: same window
     terms as ``vmem_estimate_sliced``, the mask output tile swapped for
     the slot table + count accumulators plus the epilogue transient."""
     est = vmem_estimate_sliced(widths, tb, tl, tpu_form=tpu_form)
     est -= tb * tl                          # no [tb, tl] bool output tile
     est += tb * (kp + 1) * 4                # slot table + count accumulators
-    est += tb * tl * (kc if tpu_form else 1) * 4  # epilogue transient
+    est += epilogue_vmem(tb, tl, tpu_form)
     return est
 
 
@@ -390,15 +395,6 @@ def _make_kernel(n_int: int, tb: int, tl: int, tpu_form: bool,
     return kernel
 
 
-# The compaction epilogues moved to ``kernels.epilogue`` (they are shared
-# with ``mlp_infer`` and ``delta_probe``); the old private names stay
-# importable here for back-compat.
-from repro.kernels.epilogue import (  # noqa: E402
-    compact_epilogue_interp as _compact_epilogue_interp,
-    compact_epilogue_tpu as _compact_epilogue_tpu,
-)
-
-
 def _make_compact_kernel(n_int: int, tb: int, tl: int, kp: int, n_j: int,
                          tpu_form: bool, sub_tl: int = SUB_TL,
                          kc: int = COMPACT_KC):
@@ -413,12 +409,11 @@ def _make_compact_kernel(n_int: int, tb: int, tl: int, kp: int, n_j: int,
     VMEM-resident across the whole leaf-tile sweep of a query tile: the
     mask never exists outside registers/VMEM.
 
-    ``tpu_form=True`` realizes the scatter as ``COMPACT_KC``-wide chunks of
-    rank-equality compares + lane-sum (ranks are unique per row, so sum ==
-    select — Mosaic vectorizes dense compare/reduce where it would not a
-    lane scatter); each chunk is ``pl.when``-guarded by the tile's
-    [min, max] rank range so a tile only touches the slot chunks it can
-    actually fill, and the whole epilogue is skipped for dead tiles.
+    ``tpu_form=True`` realizes the scatter with ``epilogue``'s TPU form
+    (prefix count on the MXU, then one rank-equality compare + lane-sum
+    per in-tile rank — ranks are unique per row, so sum == select, and
+    Mosaic vectorizes dense compare/reduce where it would not a lane
+    scatter); the whole epilogue is skipped for dead tiles.
     ``tpu_form=False`` fills slots by value-level rowwise binary search of
     each slot's rank over the tile's inclusive prefix count — the same
     searchsorted scheme as ``compact_mask_counted``, unconditional value
@@ -545,6 +540,7 @@ def traverse_fused_t(q_t: jnp.ndarray,
         out_shape=jax.ShapeDtypeStruct((B, L), jnp.bool_),
         scratch_shapes=[pltpu.VMEM((tb, n_last), jnp.float32)],
         interpret=interpret,
+        name="traverse_fused",
     )(*args)
 
 
@@ -612,6 +608,7 @@ def traverse_compact_t(q_t: jnp.ndarray,
                    jax.ShapeDtypeStruct((B, 1), jnp.int32)],
         scratch_shapes=[pltpu.VMEM((tb, n_last), jnp.float32)],
         interpret=interpret,
+        name="traverse_compact",
     )(*args)
 
 
@@ -877,6 +874,7 @@ def traverse_fused_sliced_t(starts: jnp.ndarray,
             pl.BlockSpec((tb, tl), lambda i, j, s: (i, j))),
         out_shape=jax.ShapeDtypeStruct((B, L), jnp.bool_),
         interpret=interpret,
+        name="traverse_fused_sliced",
     )(starts.astype(jnp.int32), *args)
 
 
@@ -930,4 +928,5 @@ def traverse_compact_sliced_t(starts: jnp.ndarray,
         out_shape=[jax.ShapeDtypeStruct((B, kp), jnp.int32),
                    jax.ShapeDtypeStruct((B, 1), jnp.int32)],
         interpret=interpret,
+        name="traverse_compact_sliced",
     )(starts.astype(jnp.int32), *args)

@@ -34,7 +34,6 @@ import jax.numpy as jnp
 
 from repro import configs
 from repro.launch import sharding as shd
-from repro.launch import mesh as pmesh
 from repro.launch.mesh import make_production_mesh
 from repro.launch.specs import (ACCUM, SHAPE_DEFS, cell_supported,
                                 decode_specs, input_specs, state_specs)
@@ -170,7 +169,7 @@ def _airtree_cell(shape: str, multi_pod: bool):
     levels = (Level(mbrs=f32(1, 4), parent=i32(1)),
               Level(mbrs=f32(128, 4), parent=i32(128)),
               Level(mbrs=f32(L, 4), parent=i32(L)))
-    tree = DeviceTree(levels=levels, leaf_entries=f32(L, M, 2),
+    tree = DeviceTree(levels=levels, leaf_entries=f32(L, 2, M),
                       leaf_entry_ids=i32(L, M), leaf_counts=i32(L),
                       n_points=2_000_000, max_entries=M)
     bank = KNNBank(feats=f32(C, Qp, 4), labels=f32(C, Qp, Cl),
@@ -189,7 +188,7 @@ def _airtree_cell(shape: str, multi_pod: bool):
                            max_pred=16, score_union=union)
     step = eng.make_serve_step(mesh, cfg, kind="knn")
     q_spec = f32(B, 4)
-    with pmesh.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = jax.jit(step).lower(h, q_spec)
     meta = dict(arch="airtree", shape=shape,
                 mesh="2x16x16" if multi_pod else "16x16", kind="serve",
@@ -225,7 +224,7 @@ def lower_cell(arch: str, shape: str, *, multi_pod: bool = False,
         batch_spec = input_specs(cfg, shape)
         in_sh = (shd.params_shardings(state_spec, mesh),
                  shd.batch_shardings(batch_spec, mesh))
-        with pmesh.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jax.jit(step, in_shardings=in_sh).lower(
                 state_spec, batch_spec)
         return lowered, mesh, meta
@@ -241,7 +240,7 @@ def lower_cell(arch: str, shape: str, *, multi_pod: bool = False,
 
         in_sh = (shd.params_shardings(params_spec, mesh),
                  shd.batch_shardings(batch_spec, mesh))
-        with pmesh.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             lowered = jax.jit(prefill, in_shardings=in_sh).lower(
                 params_spec, batch_spec)
         return lowered, mesh, meta
@@ -256,7 +255,7 @@ def lower_cell(arch: str, shape: str, *, multi_pod: bool = False,
     in_sh = (shd.params_shardings(params_spec, mesh),
              shd.cache_shardings(cache_spec, mesh),
              shd.batch_shardings(tok_spec, mesh)["tokens"])
-    with pmesh.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         lowered = jax.jit(serve_step, in_shardings=in_sh).lower(
             params_spec, cache_spec, tok_spec["tokens"])
     meta["cache_bytes_global"] = sum(
@@ -316,7 +315,7 @@ def _lower_for_cost(cfg: ModelConfig, shape: str, mesh):
         batch_spec = input_specs(cfg, shape)
         in_sh = (shd.params_shardings(state_spec, mesh),
                  shd.batch_shardings(batch_spec, mesh))
-        with pmesh.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             return jax.jit(step, in_shardings=in_sh).lower(state_spec,
                                                            batch_spec)
     params_spec = jax.eval_shape(
@@ -327,7 +326,7 @@ def _lower_for_cost(cfg: ModelConfig, shape: str, mesh):
         fn = lambda p, b: tf.forward(cfg, p, b, remat_policy=None)  # noqa
         in_sh = (shd.params_shardings(params_spec, mesh),
                  shd.batch_shardings(batch_spec, mesh))
-        with pmesh.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             return jax.jit(fn, in_shardings=in_sh).lower(params_spec,
                                                          batch_spec)
     from repro.serving import decode as dec
@@ -336,7 +335,7 @@ def _lower_for_cost(cfg: ModelConfig, shape: str, mesh):
     in_sh = (shd.params_shardings(params_spec, mesh),
              shd.cache_shardings(cache_spec, mesh),
              shd.batch_shardings(tok_spec, mesh)["tokens"])
-    with pmesh.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         return jax.jit(fn, in_shardings=in_sh).lower(
             params_spec, cache_spec, tok_spec["tokens"])
 

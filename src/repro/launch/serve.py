@@ -36,7 +36,9 @@ visible to its segment.
 from __future__ import annotations
 
 import argparse
+import functools
 import time
+from typing import Callable, NamedTuple
 
 import jax
 import jax.numpy as jnp
@@ -49,89 +51,127 @@ from repro.core.hybrid import hybrid_query
 from repro.core.monitor import DefaultPolicy, EngineFreshServer, FreshServer
 from repro.core.rtree import RTree
 from repro.launch import mesh as pmesh
+from repro.launch.compile_cache import enable_compile_cache
 from repro.data import arrivals as arrv, synth
 
 
-def make_serve_fns(hyb, args, devices):
-    """(narrow_fn, wide_fn, trunc_field, ctx, ai_fused) for the loop.
+def engine_mesh(devices):
+    """The serving mesh over ``devices``: half the devices on ``data``
+    (traffic), the rest on ``model`` (leaves and experts). Returns
+    ``(mesh, n_model)``."""
+    n = len(devices)
+    nd = max(1, n // 2)
+    return pmesh.make_mesh((nd, n // nd), ("data", "model"),
+                           devices=devices), n // nd
+
+
+def engine_config(hyb, args) -> engine.EngineConfig:
+    """The engine's bounds for serving ``hyb``: the AI path keeps the
+    tree's own ``max_pred``/``max_cells``/threshold (so the sharded
+    engine answers as the single-device path does), the R path the
+    ``--max-visited`` narrow bound per shard."""
+    return engine.EngineConfig(
+        max_visited=args.max_visited, max_pred=hyb.ait.max_pred,
+        max_cells=hyb.ait.max_cells, threshold=hyb.ait.threshold,
+        use_kernel=args.kernel)
+
+
+class ServeFns(NamedTuple):
+    """The two-tier range serving steps and what they serve.
+
+    ``step``/``wide_step`` are jitted ``(hybrid, queries) → stats`` (the
+    narrow and wide bounds); ``hybrid`` is the served tree (padded and
+    placed on the mesh when distributed); ``trunc_field`` names the
+    overflow flag the scheduler re-serves; ``ai_fused`` reports whether
+    the AI path's prediction really dispatches the fused kernel under
+    this configuration — asked of the dispatch gate at the shapes it will
+    see (per-shard for the engine), because ``REPRO_KERNELS=off`` or the
+    VMEM gate route to the dense oracle in interpret mode.
+
+    Serving sets no ambient mesh: the engine's steps carry theirs in the
+    ``shard_map`` and in the placed tree, while under ``jax.set_mesh``
+    the scheduler's eager key kernel would be partitioned over the mesh,
+    which a Mosaic kernel cannot be."""
+    step: Callable
+    wide_step: Callable
+    hybrid: object
+    trunc_field: str
+    ai_fused: bool
+
+    def narrow(self, q):
+        return self.step(self.hybrid, q)
+
+    def wide(self, q):
+        return self.wide_step(self.hybrid, q)
+
+
+def make_serve_fns(hyb, args, devices) -> ServeFns:
+    """Range serving steps for the stream loop.
 
     Distributed (>1 device and ``--distributed``): the shard_map engine's
-    two-tier steps (overflow flag ``ServeStats.r_truncated``). Otherwise:
-    jit'd ``hybrid_query`` with the same narrow/wide bound split (flag
+    two-tier steps (overflow flag ``ServeStats.r_truncated``), with the
+    tree's leaves and experts placed over ``model``. Otherwise:
+    ``hybrid_query`` with the same narrow/wide bound split (flag
     ``HybridResult.truncated``; the wide tier also widens ``max_results``
-    so its result-id gather cannot re-truncate). ``ai_fused`` reports
-    whether the AI path's prediction actually dispatches the fused
-    kernel under *this* configuration — asked of the dispatch gate at
-    the shapes it will really see (per-shard for the engine), because
-    ``REPRO_KERNELS=off`` or the VMEM gate silently route to the dense
-    oracle even with ``--kernel``.
+    so its result-id gather cannot re-truncate).
     """
     from repro.kernels import ops as kops
     want_fused = args.kernel and args.classifier == "mlp"
     if args.distributed and len(devices) > 1:
-        n = len(devices)
-        nd = max(1, n // 2)
-        n_model = n // nd
-        mesh = jax.make_mesh((nd, n_model), ("data", "model"))
+        mesh, n_model = engine_mesh(devices)
+        nd = len(devices) // n_model
         hyb_s = engine.pad_tree_for_sharding(hyb, n_model)
-        cfg = engine.EngineConfig(max_visited=args.max_visited,
-                                  use_kernel=args.kernel)
+        hyb_s = jax.device_put(hyb_s, engine.tree_shardings(hyb_s, mesh))
+        cfg = engine_config(hyb, args)
         narrow, wide = engine.make_two_tier_steps(
             mesh, cfg, kind=args.classifier, wide_factor=args.wide_factor)
-        ctx = pmesh.set_mesh(mesh)
         fused = want_fused and cfg.score_union == "topk" and \
             kops.mlp_fused_active(
                 args.batch // nd, hyb_s.ait.bank, cfg.max_cells,
                 hyb_s.tree.n_leaves, cfg.max_pred,
                 n_cells=hyb_s.ait.bank.w1.shape[0] // n_model)
         # jit once per tier — the stream re-enters the step per batch
-        return (jax.jit(lambda q: narrow(hyb_s, q)),
-                jax.jit(lambda q: wide(hyb_s, q)), "r_truncated", ctx,
-                fused)
+        return ServeFns(jax.jit(narrow), jax.jit(wide), hyb_s,
+                        "r_truncated", fused)
 
-    import contextlib
     mv, mr = args.max_visited, 512
-    narrow = jax.jit(lambda q: hybrid_query(hyb, q, max_visited=mv,
-                                            max_results=mr,
-                                            use_kernel=args.kernel))
-    wide = jax.jit(lambda q: hybrid_query(
-        hyb, q, max_visited=mv * args.wide_factor,
+    narrow = jax.jit(functools.partial(
+        hybrid_query, max_visited=mv, max_results=mr,
+        use_kernel=args.kernel))
+    wide = jax.jit(functools.partial(
+        hybrid_query, max_visited=mv * args.wide_factor,
         max_results=mr * args.wide_factor, use_kernel=args.kernel))
     fused = want_fused and kops.mlp_fused_active(
         args.batch, hyb.ait.bank, hyb.ait.max_cells,
         hyb.tree.n_leaves, hyb.ait.max_pred)
-    return narrow, wide, "truncated", contextlib.nullcontext(), fused
+    return ServeFns(narrow, wide, hyb, "truncated", fused)
 
 
 def make_fresh_server(base, hyb, args, devices, fit_state=None,
                       policy=None):
     """Build the mixed-stream server: ``FreshServer`` (single-device
     hybrid path) or ``EngineFreshServer`` (shard_map engine, replicated
-    delta) plus the mesh context. ``fit_state``/``policy`` turn on the
-    online instance-optimization loop (span-diff repacks + incremental
+    delta). ``fit_state``/``policy`` turn on the online
+    instance-optimization loop (span-diff repacks + incremental
     ``refit_cells`` chunks between segments)."""
-    import contextlib
     if args.distributed and len(devices) > 1:
-        n = len(devices)
-        nd = max(1, n // 2)
-        n_model = n // nd
-        mesh = jax.make_mesh((nd, n_model), ("data", "model"))
-        cfg = engine.EngineConfig(max_visited=args.max_visited,
-                                  use_kernel=args.kernel)
-        srv = EngineFreshServer(base, hyb, mesh, cfg, kind=args.classifier,
-                                n_model=n_model, delta_cap=args.delta_cap,
-                                wide_factor=args.wide_factor,
-                                fit_state=fit_state, policy=policy)
-        return srv, pmesh.set_mesh(mesh)
-    srv = FreshServer(base, hyb, delta_cap=args.delta_cap,
-                      max_visited=args.max_visited, max_results=512,
-                      wide_factor=args.wide_factor, use_kernel=args.kernel,
-                      fit_state=fit_state, policy=policy)
-    return srv, contextlib.nullcontext()
+        mesh, n_model = engine_mesh(devices)
+        return EngineFreshServer(base, hyb, mesh, engine_config(hyb, args),
+                                 kind=args.classifier, n_model=n_model,
+                                 delta_cap=args.delta_cap,
+                                 wide_factor=args.wide_factor,
+                                 fit_state=fit_state, policy=policy)
+    return FreshServer(base, hyb, delta_cap=args.delta_cap,
+                       max_visited=args.max_visited, max_results=512,
+                       wide_factor=args.wide_factor, use_kernel=args.kernel,
+                       fit_state=fit_state, policy=policy)
 
 
-def serve_mixed(base, extra, hyb, wl, args, rep) -> None:
-    """Drive the mixed read/write stream and report freshness stats."""
+def serve_mixed(base, extra, hyb, wl, args, rep
+                ) -> tuple[int, schedule.MixedReport]:
+    """Drive the mixed read/write stream and report freshness stats.
+    Returns the freshness oracle's mismatch count and the stream
+    report."""
     fit_state = policy = None
     if args.policy != "none":
         # repack/demote/promote run regardless; without a per-cell
@@ -142,16 +182,15 @@ def serve_mixed(base, extra, hyb, wl, args, rep) -> None:
                                repack_at=args.repack_at)
         if rep.fit_state is not None and args.classifier != "forest":
             fit_state = rep.fit_state
-    server, ctx = make_fresh_server(base, hyb, args, jax.devices(),
-                                    fit_state=fit_state, policy=policy)
+    server = make_fresh_server(base, hyb, args, jax.devices(),
+                               fit_state=fit_state, policy=policy)
     bbox = schedule.workload_bbox(wl.queries)
-    with ctx:
-        t0 = time.time()
-        mixed = schedule.serve_mixed_workload(
-            server, wl.queries, extra, batch=args.batch, sort=args.sort,
-            bbox=bbox, insert_every=args.insert_every,
-            repack_every=args.repack_every)
-        dt_s = time.time() - t0
+    t0 = time.time()
+    mixed = schedule.serve_mixed_workload(
+        server, wl.queries, extra, batch=args.batch, sort=args.sort,
+        bbox=bbox, insert_every=args.insert_every,
+        repack_every=args.repack_every)
+    dt_s = time.time() - t0
     st = mixed.stats
     fs = server.stats()
     trunc_field = getattr(server, "trunc_field", "truncated")
@@ -202,6 +241,7 @@ def serve_mixed(base, extra, hyb, wl, args, rep) -> None:
             mism += int(np.sum(exp != got[o:min(o + 256, hi)]))
     print(f"# oracle: {mism} / {mixed.n_queries} n_results mismatches vs "
           f"per-segment brute-force containment")
+    return mism, mixed
 
 
 def serve_open_loop(narrow_fn, wide_fn, trunc_field, wl, args) -> None:
@@ -269,11 +309,11 @@ def _timed_stream(narrow_fn, q, args, *, wide_fn=None, trunc_field=None,
     return report, (time.time() - t0) / args.reps
 
 
-def serve_knn(dtree, pts, args) -> None:
+def serve_knn(dtree, pts, args) -> int:
     """kNN stream: distance browsing at a density-derived radius, with
     the radius-doubling wide tier re-serving flagged rows; a brute-force
     k-distance oracle checks a sample bit-exactly (prefix property on
-    rows still truncated)."""
+    rows still truncated). Returns the oracle's mismatch count."""
     from repro.core import knn as knnlib
     rng = np.random.default_rng(0)
     centers = pts[rng.integers(0, pts.shape[0], args.queries)].astype(
@@ -313,6 +353,7 @@ def serve_knn(dtree, pts, args) -> None:
         mism += int(not np.array_equal(got[j, :kk], bd2[j, :kk]))
     print(f"# oracle: {mism} / {m} sampled rows mismatch brute-force "
           f"k-distances (bit-exact)")
+    return mism
 
 
 def serve_join(dtree, pts, args) -> None:
@@ -362,31 +403,24 @@ def serve_point(hyb, base, args, devices) -> None:
     """Point-query stream: degenerate rects at dataset points served
     with single-cell AI routing and narrowed bounds — no wide tier, so
     exactness is *asserted* (zero truncated rows) instead of re-served."""
-    import contextlib
     from repro.core import hybrid as hybmod
     rng = np.random.default_rng(0)
     ppts = base[rng.integers(0, base.shape[0], args.queries)].astype(
         np.float32)
     q = np.concatenate([ppts, ppts], axis=1)
     if args.distributed and len(devices) > 1:
-        n = len(devices)
-        nd = max(1, n // 2)
-        n_model = n // nd
-        mesh = jax.make_mesh((nd, n_model), ("data", "model"))
+        mesh, n_model = engine_mesh(devices)
         hyb_s = engine.pad_tree_for_sharding(hyb, n_model)
-        cfg = engine.EngineConfig(max_visited=args.max_visited,
-                                  use_kernel=args.kernel)
-        step = engine.make_point_serve_step(mesh, cfg,
+        step = engine.make_point_serve_step(mesh, engine_config(hyb, args),
                                             kind=args.classifier)
         narrow = jax.jit(lambda qq: step(hyb_s, qq))
-        trunc_field, ctx = "r_truncated", pmesh.set_mesh(mesh)
+        trunc_field = "r_truncated"
     else:
         narrow = jax.jit(lambda qq: hybmod.point_query(
             hyb, qq, use_kernel=args.kernel))
-        trunc_field, ctx = "truncated", contextlib.nullcontext()
-    with ctx:
-        report, dt_s = _timed_stream(narrow, q, args,
-                                     bbox=schedule.workload_bbox(q))
+        trunc_field = "truncated"
+    report, dt_s = _timed_stream(narrow, q, args,
+                                 bbox=schedule.workload_bbox(q))
     st = report.stats
     resid = int(np.asarray(getattr(st, trunc_field)).sum())
     acc = float(np.asarray(st.leaf_accesses).mean())
@@ -415,7 +449,61 @@ def serve_point(hyb, base, args, devices) -> None:
           f"containment")
 
 
-def main() -> None:
+def serve_range(fns: ServeFns, wl, args
+                ) -> tuple[int, schedule.ServeReport]:
+    """Closed-loop range stream: warm both tiers, time ``--reps`` full
+    streams through the spatial scheduler, report the stream stats and
+    the no-drop oracle (every query's count against the workload
+    labels). Returns the oracle's mismatch count and the stream report
+    (per-query stats in submission order)."""
+    bbox = schedule.workload_bbox(wl.queries)
+    # warm / compile both tiers, then time full-stream repetitions
+    report = schedule.serve_workload(
+        fns.narrow, wl.queries, batch=args.batch, sort=args.sort,
+        bbox=bbox, wide_fn=fns.wide, trunc_field=fns.trunc_field)
+    t0 = time.time()
+    for _ in range(args.reps):
+        report = schedule.serve_workload(
+            fns.narrow, wl.queries, batch=args.batch, sort=args.sort,
+            bbox=bbox, wide_fn=fns.wide, trunc_field=fns.trunc_field)
+    dt_s = (time.time() - t0) / args.reps
+
+    st = report.stats
+    acc = float(np.asarray(st.leaf_accesses).mean())
+    ai = float(np.asarray(st.used_ai).mean())
+    resid = int(np.asarray(getattr(st, fns.trunc_field)).sum())
+    print(f"# stream: {report.n_queries} queries in {report.n_batches} "
+          f"batches (sort={report.sort}), {report.n_reserved} re-served "
+          f"wide ({report.wide_batches} batches), {resid} still truncated")
+    print(f"# serve: {report.n_queries/dt_s:.0f} queries/s, "
+          f"{acc:.2f} leaf accesses/query, "
+          f"{100*ai:.1f}% answered by the AI path")
+    # AI-path fusion accounting: with the fused prediction kernel (mlp
+    # bank + --kernel) prediction flows through the compact [B, max_pred]
+    # slot table and the dense [B, L] score table never materializes;
+    # every other configuration still runs the dense-oracle rung, so
+    # report the saving only when it actually happened.
+    k = fns.hybrid.ait.max_pred
+    n_leaves = fns.hybrid.tree.n_leaves
+    dense_b = report.n_queries * n_leaves * 4
+    slot_b = report.n_queries * (k + 1) * 4
+    verdict = ("eliminated" if fns.ai_fused else
+               "still materialized on this config — fused path needs "
+               "--classifier mlp --kernel (and the kernel dispatch "
+               "active)")
+    print(f"# AI path: {slot_b/1e3:.0f} KB compact slot tables; "
+          f"{dense_b/1e6:.1f} MB dense [B, {n_leaves}] score "
+          f"tables {verdict}")
+    # no-drop oracle: the labelling pass already executed every query
+    mism = int(np.sum(np.asarray(st.n_results) != wl.n_results))
+    print(f"# oracle: {mism} / {report.n_queries} n_results mismatches "
+          f"vs workload labels")
+    return mism, report
+
+
+def parse_args(argv=None) -> argparse.Namespace:
+    """The command-line options (``argv=None`` reads ``sys.argv``). On a
+    TPU the kernel paths always serve, whatever ``--kernel`` says."""
     p = argparse.ArgumentParser()
     p.add_argument("--dataset", default="tweets", choices=("tweets",
                                                            "crimes"))
@@ -437,7 +525,8 @@ def main() -> None:
     p.add_argument("--kernel", action="store_true",
                    help="serve through the Pallas kernel paths (fused "
                         "traversal/compaction; with --classifier mlp also "
-                        "the fused prediction kernel)")
+                        "the fused prediction kernel) in interpret mode; "
+                        "on a TPU the kernels always serve")
     p.add_argument("--distributed", action="store_true",
                    help="serve through the shard_map engine")
     p.add_argument("--insert-rate", type=float, default=0.0,
@@ -495,11 +584,18 @@ def main() -> None:
     p.add_argument("--join-pairs", type=int, default=16,
                    help="narrow-tier pair-slot width for --query-type "
                         "join")
-    args = p.parse_args()
+    args = p.parse_args(argv)
     if args.query_type != "range" and (args.insert_rate > 0
                                        or args.arrival != "closed"):
         p.error("--query-type point/knn/join drive the closed-loop "
                 "read-only stream (no --insert-rate / --arrival)")
+    args.kernel = args.kernel or jax.default_backend() == "tpu"
+    return args
+
+
+def main() -> None:
+    args = parse_args()
+    print(f"# compile cache: {enable_compile_cache()}")
 
     gen = synth.tweets_like if args.dataset == "tweets" else synth.crimes_like
     pts = gen(args.points)
@@ -541,55 +637,12 @@ def main() -> None:
         serve_mixed(base, extra, hyb, wl, args, rep)
         return
 
-    narrow_fn, wide_fn, trunc_field, ctx, ai_fused = make_serve_fns(
-        hyb, args, jax.devices())
+    fns = make_serve_fns(hyb, args, jax.devices())
     if args.arrival != "closed":
-        with ctx:
-            serve_open_loop(narrow_fn, wide_fn, trunc_field, wl, args)
+        serve_open_loop(fns.narrow, fns.wide, fns.trunc_field, wl, args)
         return
 
-    bbox = schedule.workload_bbox(wl.queries)
-    with ctx:
-        # warm / compile both tiers, then time full-stream repetitions
-        report = schedule.serve_workload(
-            narrow_fn, wl.queries, batch=args.batch, sort=args.sort,
-            bbox=bbox, wide_fn=wide_fn, trunc_field=trunc_field)
-        t0 = time.time()
-        for _ in range(args.reps):
-            report = schedule.serve_workload(
-                narrow_fn, wl.queries, batch=args.batch, sort=args.sort,
-                bbox=bbox, wide_fn=wide_fn, trunc_field=trunc_field)
-        dt_s = (time.time() - t0) / args.reps
-
-    st = report.stats
-    acc = float(np.asarray(st.leaf_accesses).mean())
-    ai = float(np.asarray(st.used_ai).mean())
-    resid = int(np.asarray(getattr(st, trunc_field)).sum())
-    print(f"# stream: {report.n_queries} queries in {report.n_batches} "
-          f"batches (sort={report.sort}), {report.n_reserved} re-served "
-          f"wide ({report.wide_batches} batches), {resid} still truncated")
-    print(f"# serve: {report.n_queries/dt_s:.0f} queries/s, "
-          f"{acc:.2f} leaf accesses/query, "
-          f"{100*ai:.1f}% answered by the AI path")
-    # AI-path fusion accounting: with the fused prediction kernel (mlp
-    # bank + --kernel) prediction flows through the compact [B, max_pred]
-    # slot table and the dense [B, L] score table never materializes;
-    # every other configuration still runs the dense-oracle rung, so
-    # report the saving only when it actually happened.
-    k = hyb.ait.max_pred
-    dense_b = report.n_queries * dtree.n_leaves * 4
-    slot_b = report.n_queries * (k + 1) * 4
-    verdict = ("eliminated" if ai_fused else
-               "still materialized on this config — fused path needs "
-               "--classifier mlp --kernel (and the kernel dispatch "
-               "active)")
-    print(f"# AI path: {slot_b/1e3:.0f} KB compact slot tables; "
-          f"{dense_b/1e6:.1f} MB dense [B, {dtree.n_leaves}] score tables "
-          f"{verdict}")
-    # no-drop oracle: the labelling pass already executed every query
-    mism = int(np.sum(np.asarray(st.n_results) != wl.n_results))
-    print(f"# oracle: {mism} / {report.n_queries} n_results mismatches "
-          f"vs workload labels")
+    serve_range(fns, wl, args)
 
 
 if __name__ == "__main__":

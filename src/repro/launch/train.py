@@ -16,6 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import configs
+from repro.launch import mesh as pmesh
 from repro.launch import sharding as shd
 from repro.training import checkpoint, fault_tolerance, optimizer as opt
 from repro.training import train_loop
@@ -62,10 +63,10 @@ def main() -> None:
 
     n_dev = len(jax.devices())
     if args.mesh == "auto":
-        mesh = jax.make_mesh((n_dev, 1), ("data", "model"))
+        mesh = pmesh.make_mesh((n_dev, 1), ("data", "model"))
     else:
         d, m = (int(x) for x in args.mesh.split("x"))
-        mesh = jax.make_mesh((d, m), ("data", "model"))
+        mesh = pmesh.make_mesh((d, m), ("data", "model"))
 
     ocfg = opt.AdamWConfig(lr=args.lr, warmup_steps=10,
                            decay_steps=max(args.steps, 100))

@@ -42,14 +42,14 @@ def main() -> int:
         ait=dataclasses.replace(hyb.ait,
                                 cell_ok=jnp.zeros_like(hyb.ait.cell_ok)))
 
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = pmesh.make_mesh((2, 2, 2), ("pod", "data", "model"))
     hyb_p = engine.pad_tree_for_sharding(hyb, 2)
     hyb2_p = engine.pad_tree_for_sharding(hyb2, 2)
     q = jnp.asarray(wl.queries[:64])
     cfg = engine.EngineConfig(max_visited=256, max_pred=32)
     step = engine.make_serve_step(mesh, cfg, kind="knn")
     ok = True
-    with pmesh.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         with_delta = step(hyb_p, q, store.xy)
         rebuilt = step(hyb2_p, q)
         repacked = step(hyb2_p, q, empty.xy)

@@ -26,7 +26,7 @@ def main() -> int:
     wl = labels.make_workload(dtree, qs)
     hyb, _ = build.fit_airtree(dtree, wl, kind="knn", grid_sizes=(8,))
 
-    mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+    mesh = pmesh.make_mesh((2, 2, 2), ("pod", "data", "model"))
     hyb_p = engine.pad_tree_for_sharding(hyb, 2)
     B = 64
     q = jnp.asarray(wl.queries[:B])
@@ -35,7 +35,7 @@ def main() -> int:
     for union in ("pmax", "topk"):
         step = engine.make_serve_step(mesh, engine.EngineConfig(
             max_visited=64, max_pred=32, score_union=union), kind="knn")
-        with pmesh.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             stats = step(hyb_p, q)
         checks = {
             "n_results": np.array_equal(np.asarray(stats.n_results),

@@ -28,6 +28,7 @@ from repro.core.rtree import RTree
 from repro.data import synth
 from repro.kernels import delta_probe as dpk
 from repro.kernels import ops, ref
+from tests.helpers.banks import synth_bank, synth_hybrid
 from tests.helpers.hypo import given, settings, st
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -224,7 +225,6 @@ def _synth_fresh_world(rng, n_base, n_ins, n_q):
     """Untrained hybrid over a real STR tree: the bank never predicts
     (all queries fall back to the exact R path), so the property is
     pinned on serving mechanics, not training quality."""
-    from tests.test_mlp_infer import synth_bank
     from repro.core.aitree import make_aitree
     from repro.core.classifiers.router import Router
     from repro.core.grid import Grid
@@ -399,12 +399,12 @@ def test_engine_delta_matches_rebuild():
         hyb, tree=dtree2,
         ait=dataclasses.replace(hyb.ait,
                                 cell_ok=jnp.zeros_like(hyb.ait.cell_ok)))
-    mesh = jax.make_mesh((1, 1, 1), ("pod", "data", "model"))
+    mesh = pmesh.make_mesh((1, 1, 1), ("pod", "data", "model"))
     q = jnp.asarray(wl.queries[:64])
     for uk in (False, True):
         step = engine.make_serve_step(mesh, engine.EngineConfig(
             max_visited=256, max_pred=32, use_kernel=uk), kind="knn")
-        with pmesh.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             with_delta = step(hyb, q, store.xy)
             rebuilt = step(hyb2, q)
         np.testing.assert_array_equal(np.asarray(with_delta.n_results),
@@ -454,19 +454,18 @@ def test_engine_delta_path_hlo_stays_compact():
     slot-table contract instead of breaking it."""
     import re
     from repro.launch import mesh as pmesh
-    from tests.test_mlp_infer import _synth_hybrid
     rng = np.random.default_rng(10)
-    hyb = _synth_hybrid(rng)                  # L = 1000
+    hyb = synth_hybrid(rng)                  # L = 1000
     cap = 600
     pts = _buffer(rng, cap, 300)
-    mesh = jax.make_mesh((1, 1, 1), ("pod", "data", "model"))
+    mesh = pmesh.make_mesh((1, 1, 1), ("pod", "data", "model"))
     B = 256
     lo = rng.uniform(-1, 0.9, (B, 2))
     q = jnp.asarray(np.concatenate([lo, lo + 0.05], 1), jnp.float32)
     step = engine.make_serve_step(mesh, engine.EngineConfig(
         max_visited=64, max_pred=16, use_kernel=True, score_union="topk"),
         kind="mlp")
-    with pmesh.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         txt = jax.jit(step).lower(hyb, q, pts).as_text()
     assert not re.search(r"<256x600x", txt), \
         "delta path materialized the [B, cap] mask"
@@ -554,13 +553,13 @@ def test_engine_guard_matches_hybrid(under_trained_world):
     defaults on."""
     from repro.launch import mesh as pmesh
     hyb, _, wl = under_trained_world
-    mesh = jax.make_mesh((1, 1, 1), ("pod", "data", "model"))
+    mesh = pmesh.make_mesh((1, 1, 1), ("pod", "data", "model"))
     q = jnp.asarray(wl.queries[:64])
     ref_res = hybrid_query(hyb, q, max_visited=256)
     assert engine.EngineConfig().guard
     step = engine.make_serve_step(mesh, engine.EngineConfig(
         max_visited=256, max_pred=64), kind="mlp")
-    with pmesh.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         stats = step(hyb, q)
     for f in ("n_results", "used_ai", "guarded", "leaf_accesses"):
         np.testing.assert_array_equal(np.asarray(getattr(stats, f)),

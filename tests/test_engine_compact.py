@@ -27,7 +27,7 @@ def _single_level_tree(L=6, seed=5):
     mbrs = jnp.asarray(np.concatenate([lo, lo + w], 1).astype(np.float32))
     tree = DeviceTree(
         levels=(Level(mbrs=mbrs, parent=jnp.zeros((L,), jnp.int32)),),
-        leaf_entries=jnp.full((L, 8, 2), jnp.inf, jnp.float32),
+        leaf_entries=jnp.full((L, 2, 8), jnp.inf, jnp.float32),
         leaf_entry_ids=jnp.full((L, 8), -1, jnp.int32),
         leaf_counts=jnp.zeros((L,), jnp.int32),
         n_points=0, max_entries=8)
@@ -96,13 +96,13 @@ def test_engine_r_path_kernel_bit_identical():
     qs = synth.synth_queries(pts, 1e-4, 200, seed=1)
     wl = labels.make_workload(dtree, qs)
     hyb, _ = build.fit_airtree(dtree, wl, kind="knn", grid_sizes=(6,))
-    mesh = jax.make_mesh((1, 1, 1), ("pod", "data", "model"))
+    mesh = pmesh.make_mesh((1, 1, 1), ("pod", "data", "model"))
     q = jnp.asarray(wl.queries[:64])
     stats = {}
     for uk in (False, True):
         step = engine.make_serve_step(mesh, engine.EngineConfig(
             max_visited=64, max_pred=32, use_kernel=uk), kind="knn")
-        with pmesh.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             stats[uk] = step(hyb, q)
     for f in stats[False]._fields:
         np.testing.assert_array_equal(

@@ -167,7 +167,7 @@ def test_join_step_hlo_stays_compact():
     tree = DeviceTree(
         levels=tuple(Level(mbrs=jnp.asarray(m), parent=jnp.asarray(p))
                      for m, p in zip(mbrs, parents)),
-        leaf_entries=jnp.zeros((L, M, 2), jnp.float32),
+        leaf_entries=jnp.zeros((L, 2, M), jnp.float32),
         leaf_entry_ids=jnp.zeros((L, M), jnp.int32),
         leaf_counts=jnp.zeros((L,), jnp.int32),
         n_points=0, max_entries=4)

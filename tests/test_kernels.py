@@ -72,14 +72,13 @@ def test_mbr_intersect_property(B, N, seed):
                                      (64, 16, 200, 32), (17, 64, 1000, 200)])
 def test_leaf_refine_shapes(B, K, L, M):
     q = mk_rects(B)
-    entries = RNG.uniform(-1, 1, size=(L, M, 2)).astype(np.float32)
+    entries = RNG.uniform(-1, 1, size=(L, 2, M)).astype(np.float32)
     idx = RNG.integers(0, L, size=(B, K)).astype(np.int32)
     valid = (RNG.uniform(size=(B, K)) > 0.3).astype(np.int32)
     out = ops.leaf_refine(jnp.asarray(q), jnp.asarray(entries),
                           jnp.asarray(idx), jnp.asarray(valid))
-    exp = ref.leaf_refine(jnp.asarray(q), jnp.asarray(entries[..., 0]),
-                          jnp.asarray(entries[..., 1]), jnp.asarray(idx),
-                          jnp.asarray(valid))
+    exp = ref.leaf_refine(jnp.asarray(q), jnp.asarray(entries),
+                          jnp.asarray(idx), jnp.asarray(valid))
     np.testing.assert_array_equal(np.asarray(out), np.asarray(exp))
 
 
@@ -90,11 +89,10 @@ def test_leaf_refine_grid_forms_bit_identical(B, K, L, M):
     the (B, K) scalar-prefetch TPU form must agree bit for bit."""
     from repro.kernels import leaf_refine as lr
     q = mk_rects(B)
-    entries = RNG.uniform(-1, 1, size=(L, M, 2)).astype(np.float32)
+    entries = RNG.uniform(-1, 1, size=(L, 2, M)).astype(np.float32)
     idx = RNG.integers(0, L, size=(B, K)).astype(np.int32)
     valid = (RNG.uniform(size=(B, K)) > 0.3).astype(np.int32)
-    args = (jnp.asarray(q), jnp.asarray(entries[..., 0]),
-            jnp.asarray(entries[..., 1]), jnp.asarray(idx),
+    args = (jnp.asarray(q), jnp.asarray(entries), jnp.asarray(idx),
             jnp.asarray(valid))
     prefetch = lr.leaf_refine(*args, interpret=True, fold_k=False)
     folded = lr.leaf_refine(*args, interpret=True, fold_k=True)
@@ -103,7 +101,7 @@ def test_leaf_refine_grid_forms_bit_identical(B, K, L, M):
 
 def test_leaf_refine_inf_padding_never_matches():
     q = np.array([[-1e30, -1e30, 1e30, 1e30]], np.float32)  # huge query
-    entries = np.full((4, 8, 2), np.inf, np.float32)        # all padding
+    entries = np.full((4, 2, 8), np.inf, np.float32)        # all padding
     idx = np.zeros((1, 2), np.int32)
     valid = np.ones((1, 2), np.int32)
     out = np.asarray(ops.leaf_refine(jnp.asarray(q), jnp.asarray(entries),
@@ -118,7 +116,7 @@ def test_leaf_refine_property(B, K, L, seed):
     rng = np.random.default_rng(seed)
     M = int(rng.integers(4, 40))
     q = mk_rects(B, rng)
-    entries = rng.uniform(-1, 1, size=(L, M, 2)).astype(np.float32)
+    entries = rng.uniform(-1, 1, size=(L, 2, M)).astype(np.float32)
     idx = rng.integers(0, L, size=(B, K)).astype(np.int32)
     valid = (rng.uniform(size=(B, K)) > 0.5).astype(np.int32)
     out = np.asarray(ops.leaf_refine(jnp.asarray(q), jnp.asarray(entries),
@@ -129,9 +127,9 @@ def test_leaf_refine_property(B, K, L, seed):
             if not valid[b, k]:
                 assert not out[b, k].any()
             else:
-                pts = entries[idx[b, k]]
-                exp = ((pts[:, 0] >= q[b, 0]) & (pts[:, 0] <= q[b, 2])
-                       & (pts[:, 1] >= q[b, 1]) & (pts[:, 1] <= q[b, 3]))
+                px, py = entries[idx[b, k]]
+                exp = ((px >= q[b, 0]) & (px <= q[b, 2])
+                       & (py >= q[b, 1]) & (py <= q[b, 3]))
                 np.testing.assert_array_equal(out[b, k], exp)
 
 

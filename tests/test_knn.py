@@ -64,16 +64,15 @@ def test_kernel_forms_bit_identical():
                                           use_kernel=False)
     c3 = jnp.asarray(np.concatenate(
         [centers, np.full((32, 1), r * r, np.float32)], 1))
-    ex = tree.leaf_entries[..., 0]
-    ey = tree.leaf_entries[..., 1]
-    safe = jnp.clip(cv.leaf_idx, 0, ex.shape[0] - 1)
+    ent = tree.leaf_entries
+    safe = jnp.clip(cv.leaf_idx, 0, ent.shape[0] - 1)
     # the oracle must run under jit: eager jax dispatches op-by-op and
     # never FMA-contracts dx*dx + dy*dy, so it differs from any jitted
     # form by 1 ulp wherever XLA fuses the multiply-add
-    want = np.asarray(jax.jit(ref.knn_browse)(c3, ex, ey, safe, cv.valid))
+    want = np.asarray(jax.jit(ref.knn_browse)(c3, ent, safe, cv.valid))
     assert np.isfinite(want).any(), "fixture too weak: no in-radius hits"
     for fold in (False, True):
-        got = kb.knn_browse(c3, ex, ey, safe, cv.valid, interpret=True,
+        got = kb.knn_browse(c3, ent, safe, cv.valid, interpret=True,
                             fold_k=fold)
         np.testing.assert_array_equal(np.asarray(got), want,
                                       err_msg=f"fold_k={fold}")
@@ -96,15 +95,14 @@ def test_padded_slots_are_inert():
     K = 8
     idx = jnp.zeros((8, K), jnp.int32)          # all alias leaf 0
     valid = jnp.zeros((8, K), jnp.int32).at[:, :2].set(1)
-    ex = tree.leaf_entries[..., 0]
-    ey = tree.leaf_entries[..., 1]
+    ent = tree.leaf_entries
     n0 = int(tree.leaf_counts[0])
-    assert 0 < n0 < tree.leaf_entries.shape[1], "fixture: want a padded tile"
+    assert 0 < n0 < ent.shape[2], "fixture: want a padded tile"
     for form in ("oracle", "tpu", "folded"):
         if form == "oracle":
-            d2 = jax.jit(ref.knn_browse)(c3, ex, ey, idx, valid)
+            d2 = jax.jit(ref.knn_browse)(c3, ent, idx, valid)
         else:
-            d2 = kb.knn_browse(c3, ex, ey, idx, valid, interpret=True,
+            d2 = kb.knn_browse(c3, ent, idx, valid, interpret=True,
                                fold_k=form == "folded")
         d2 = np.asarray(d2)
         assert (np.isfinite(d2[:, :2]).sum(axis=-1) == n0).all(), form
@@ -241,7 +239,7 @@ def _synth_tree(L=1000, M=8):
     return DeviceTree(
         levels=tuple(Level(mbrs=jnp.asarray(m), parent=jnp.asarray(p))
                      for m, p in zip(mbrs, parents)),
-        leaf_entries=jnp.zeros((L, M, 2), jnp.float32),
+        leaf_entries=jnp.zeros((L, 2, M), jnp.float32),
         leaf_entry_ids=jnp.zeros((L, M), jnp.int32),
         leaf_counts=jnp.zeros((L,), jnp.int32),
         n_points=0, max_entries=4)

@@ -20,31 +20,12 @@ import jax.numpy as jnp
 from repro.core import engine, traversal
 from repro.core.aitree import (ai_query, ai_query_compact, make_aitree,
                                predict_compact, predict_scores)
-from repro.core.classifiers.mlp import MLPBank
-from repro.core.classifiers.router import Router
 from repro.core.device_tree import DeviceTree, Level
 from repro.core.grid import Grid
-from repro.core.hybrid import HybridTree
 from repro.kernels import mlp_infer as mi
 from repro.kernels import ops, ref
+from tests.helpers.banks import synth_bank, synth_hybrid
 from tests.helpers.hypo import given, settings, st
-
-
-def synth_bank(rng, C, L, F=4, H=8, Cl=6, pos_bias=0.0):
-    """A random (untrained) MLPBank over C cells and L global leaves."""
-    lm = rng.integers(0, L, (C, Cl)).astype(np.int32)
-    lmask = rng.uniform(size=(C, Cl)) < 0.8
-    lm[~lmask] = -1
-    return MLPBank(
-        w1=jnp.asarray(rng.normal(0, 1.0, (C, F, H)), jnp.float32),
-        b1=jnp.asarray(rng.normal(0, 1.0, (C, H)), jnp.float32),
-        w2=jnp.asarray(rng.normal(0, 1.0, (C, H, Cl)), jnp.float32),
-        b2=jnp.asarray(rng.normal(pos_bias, 0.5, (C, Cl)), jnp.float32),
-        mu=jnp.zeros((F,), jnp.float32),
-        sd=jnp.ones((F,), jnp.float32),
-        label_map=jnp.asarray(lm),
-        lmask=jnp.asarray(lmask),
-    )
 
 
 def synth_world(rng, g=3, L=300, M=8, Cl=6, max_pred=16, pos_bias=0.0,
@@ -61,7 +42,7 @@ def synth_world(rng, g=3, L=300, M=8, Cl=6, max_pred=16, pos_bias=0.0,
         jnp.float32)
     tree = DeviceTree(
         levels=(Level(mbrs=mbrs, parent=jnp.zeros((L,), jnp.int32)),),
-        leaf_entries=jnp.asarray(rng.uniform(-1, 1, (L, M, 2)), jnp.float32),
+        leaf_entries=jnp.asarray(rng.uniform(-1, 1, (L, 2, M)), jnp.float32),
         leaf_entry_ids=jnp.asarray(
             np.arange(L * M).reshape(L, M), jnp.int32),
         leaf_counts=jnp.full((L,), M, jnp.int32),
@@ -110,12 +91,24 @@ def test_ops_wrapper_matches_oracle(C, L, B, Cl, k):
     (200, 128),
 ])
 def test_kernel_forms_match_oracle(L, tl, tpu_form):
-    """Both kernel forms (one-hot MXU staging + chunked rank-equality
-    epilogue on the TPU graph; value-level gathers + searchsorted on the
-    interpret graph) against the dense oracle, with the compaction rank
-    base exercised across multiple leaf tiles and empty rows mixed in."""
+    """Both kernel forms (streamed-bank inference + candidate union +
+    rank-loop epilogue on the TPU graph; value-level gathers +
+    searchsorted on the interpret graph) against the dense oracle, with
+    the compaction rank base exercised across multiple leaf tiles and
+    empty rows mixed in."""
+    _check_forms(L, tl, tpu_form, Cl=6)
+
+
+def test_tpu_form_wide_bank_matches_oracle():
+    """A bank wider than one lane quantum: each cell slot's segment of
+    the TPU form's candidate list spans several lane tiles, and the sort
+    between the stages gathers the candidates of all segments."""
+    _check_forms(1000, 256, True, Cl=300)
+
+
+def _check_forms(L, tl, tpu_form, Cl):
     rng = np.random.default_rng(5)
-    C, Cl, S, B, k = 7, 6, 4, 21, 8
+    C, S, B, k = 7, 4, 21, 8
     bank = synth_bank(rng, C, L, Cl=Cl)
     q = jnp.asarray(rng.uniform(-1, 1, (B, 4)), jnp.float32)
     cid = jnp.asarray(rng.integers(0, C, (B, S)), jnp.int32)
@@ -127,20 +120,21 @@ def test_kernel_forms_match_oracle(L, tl, tpu_form):
         bank.lmask, n_leaves=L, k=k, threshold=0.5)
 
     LANE = mi.LANE
-    Cp = (-C) % LANE
-    F, H = 4, bank.b1.shape[1]
+    Cp = (-C) % mi.CELL_BLOCK
+    Clp = (-Cl) % LANE if tpu_form else 0
     pad = lambda a, v=0.0: jnp.concatenate(         # noqa: E731
         [a, jnp.full((Cp,) + a.shape[1:], v, a.dtype)])
+    padl = lambda a, v=0.0: jnp.concatenate(        # noqa: E731
+        [a, jnp.full(a.shape[:-1] + (Clp,), v, a.dtype)], axis=-1)
     tb = (B + 7) // 8 * 8
     padb = lambda a: jnp.concatenate(               # noqa: E731
         [a, jnp.zeros((tb - B,) + a.shape[1:], a.dtype)])
     lp = ((L + LANE - 1) // LANE * LANE + tl - 1) // tl * tl
+    lm = jnp.where(bank.lmask, bank.label_map, -1)
     idx, cnt = mi.mlp_predict_compact_t(
         padb(x), padb(cid), padb(ok.astype(jnp.int32)),
-        pad(bank.w1.reshape(C, F * H)), pad(bank.b1),
-        pad(bank.w2.reshape(C, H * Cl)), pad(bank.b2),
-        pad(bank.label_map.astype(jnp.float32), -1.0),
-        pad(bank.lmask.astype(jnp.float32)),
+        pad(bank.w1), pad(bank.b1), pad(padl(bank.w2)), pad(padl(bank.b2)),
+        pad(padl(lm, -1), -1),
         k=k, lp=lp, thr=0.5, tb=tb, tl=tl, interpret=True,
         tpu_form=tpu_form)
     count = np.asarray(cnt)[:B, 0]
@@ -330,29 +324,6 @@ def test_compact_candidates_matches_mask_compaction(B, N, k, L, seed):
 # engine: AI slot stage, kernel vs oracle, and the HLO contract
 # ---------------------------------------------------------------------------
 
-def _synth_hybrid(rng, L=1000, g=3, Cl=6, pos_bias=0.5):
-    """Synthetic HybridTree over a 2-level tree (mlp bank, tiny router)."""
-    from repro.data.synth_tree import synth_levels
-    mbrs, parents = synth_levels(L, 8, rng, str_pack=True)
-    M = 8
-    tree = DeviceTree(
-        levels=tuple(Level(mbrs=jnp.asarray(m), parent=jnp.asarray(p))
-                     for m, p in zip(mbrs, parents)),
-        leaf_entries=jnp.asarray(rng.uniform(-1, 1, (L, M, 2)), jnp.float32),
-        leaf_entry_ids=jnp.asarray(np.arange(L * M).reshape(L, M), jnp.int32),
-        leaf_counts=jnp.full((L,), M, jnp.int32),
-        n_points=L * M, max_entries=M)
-    bank = synth_bank(rng, g * g, L, Cl=Cl, pos_bias=pos_bias)
-    grid = Grid(bbox=jnp.asarray([-1.0, -1.0, 1.0, 1.0], jnp.float32), g=g)
-    ait = make_aitree(grid, bank, max_cells=4, max_pred=16)
-    router = Router(
-        feat_idx=jnp.asarray(rng.integers(0, 6, (4, 3)), jnp.int32),
-        thresh=jnp.asarray(rng.uniform(-1, 1, (4, 3)), jnp.float32),
-        tables=jnp.asarray(rng.uniform(0, 1, (4, 8, 1)), jnp.float32),
-        tau=0.75)
-    return HybridTree(tree=tree, ait=ait, router=router)
-
-
 @pytest.fixture(scope="module")
 def trained_world():
     """A small *trained* MLP world — genuine AI-path answers (the random
@@ -394,14 +365,14 @@ def test_engine_ai_path_kernel_bit_identical(trained_world, union):
     answers rows (not fallback-everywhere)."""
     from repro.launch import mesh as pmesh
     hyb, wl = trained_world
-    mesh = jax.make_mesh((1, 1, 1), ("pod", "data", "model"))
+    mesh = pmesh.make_mesh((1, 1, 1), ("pod", "data", "model"))
     q = jnp.asarray(wl.queries[:64])
     stats = {}
     for uk in (False, True):
         step = engine.make_serve_step(mesh, engine.EngineConfig(
             max_visited=64, max_pred=16, use_kernel=uk, score_union=union),
             kind="mlp")
-        with pmesh.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             stats[uk] = step(hyb, q)
     assert np.asarray(stats[True].used_ai).any(), \
         "fixture must answer some rows on the AI path"
@@ -420,15 +391,15 @@ def test_engine_ai_path_never_materializes_scores():
     import re
     from repro.launch import mesh as pmesh
     rng = np.random.default_rng(9)
-    hyb = _synth_hybrid(rng)                  # L = 1000
-    mesh = jax.make_mesh((1, 1, 1), ("pod", "data", "model"))
+    hyb = synth_hybrid(rng)                  # L = 1000
+    mesh = pmesh.make_mesh((1, 1, 1), ("pod", "data", "model"))
     B = 256
     lo = rng.uniform(-1, 0.9, (B, 2))
     q = jnp.asarray(np.concatenate([lo, lo + 0.05], 1), jnp.float32)
     step = engine.make_serve_step(mesh, engine.EngineConfig(
         max_visited=64, max_pred=16, use_kernel=True, score_union="topk"),
         kind="mlp")
-    with pmesh.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         txt = jax.jit(step).lower(hyb, q).as_text()
         step_pmax = engine.make_serve_step(mesh, engine.EngineConfig(
             max_visited=64, max_pred=16, use_kernel=True,

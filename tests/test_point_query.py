@@ -135,10 +135,10 @@ def test_engine_point_serve_step_exact():
     pts, hyb = _world()
     rng = np.random.default_rng(4)
     q = jnp.asarray(_point_queries(pts, rng, 64))
-    mesh = jax.make_mesh((1, 1, 1), ("pod", "data", "model"))
+    mesh = pmesh.make_mesh((1, 1, 1), ("pod", "data", "model"))
     cfg = engine.EngineConfig(max_visited=64, max_pred=16)
     step = engine.make_point_serve_step(mesh, cfg, kind="knn")
-    with pmesh.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         out = step(hyb, q)
     assert not np.asarray(out.r_truncated).any()
     np.testing.assert_array_equal(np.asarray(out.n_results),

@@ -41,7 +41,7 @@ def _tree(L=64, fanout=4, seed=0):
     return DeviceTree(
         levels=tuple(Level(mbrs=jnp.asarray(m), parent=jnp.asarray(p))
                      for m, p in zip(mbrs, parents)),
-        leaf_entries=jnp.asarray(rng.uniform(-1, 1, (L, 8, 2)), jnp.float32),
+        leaf_entries=jnp.asarray(rng.uniform(-1, 1, (L, 2, 8)), jnp.float32),
         leaf_entry_ids=jnp.arange(L * 8, dtype=jnp.int32).reshape(L, 8),
         leaf_counts=jnp.full((L,), 8, jnp.int32),
         n_points=L * 8, max_entries=fanout)

@@ -30,7 +30,7 @@ def _tree(L=64, fanout=4, seed=0):
     from repro.data.synth_tree import synth_levels
     rng = np.random.default_rng(seed)
     mbrs, parents = synth_levels(L, fanout, rng, str_pack=True)
-    entries = jnp.asarray(rng.uniform(-1, 1, (L, 8, 2)), jnp.float32)
+    entries = jnp.asarray(rng.uniform(-1, 1, (L, 2, 8)), jnp.float32)
     return DeviceTree(
         levels=tuple(Level(mbrs=jnp.asarray(m), parent=jnp.asarray(p))
                      for m, p in zip(mbrs, parents)),
@@ -48,7 +48,7 @@ def _single_level_tree(L=6, seed=5):
     return DeviceTree(
         levels=(Level(mbrs=mbrs, parent=jnp.zeros((L,), jnp.int32)),),
         leaf_entries=jnp.asarray(
-            rng.uniform(-1, 1, (L, 8, 2)), jnp.float32),
+            rng.uniform(-1, 1, (L, 2, 8)), jnp.float32),
         leaf_entry_ids=jnp.arange(L * 8, dtype=jnp.int32).reshape(L, 8),
         leaf_counts=jnp.full((L,), 8, jnp.int32),
         n_points=L * 8, max_entries=8)
@@ -229,11 +229,11 @@ def test_engine_two_tier_clears_r_truncated():
     qs = synth.synth_queries(pts, 2e-3, 120, seed=1)
     wl = labels.make_workload(dtree, qs)
     hyb, _ = build.fit_airtree(dtree, wl, kind="knn", grid_sizes=(6,))
-    mesh = jax.make_mesh((1, 1, 1), ("pod", "data", "model"))
+    mesh = pmesh.make_mesh((1, 1, 1), ("pod", "data", "model"))
     cfg = engine.EngineConfig(max_visited=4, max_pred=32)
     narrow, wide = engine.make_two_tier_steps(mesh, cfg, kind="knn",
                                               wide_factor=64)
-    with pmesh.set_mesh(mesh):
+    with jax.set_mesh(mesh):
         nf = jax.jit(lambda q: narrow(hyb, q))
         wf = jax.jit(lambda q: wide(hyb, q))
         rep_n = schedule.serve_workload(nf, wl.queries, batch=32,

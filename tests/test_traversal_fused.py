@@ -383,7 +383,7 @@ def test_range_query_compact_never_materializes_mask():
     dtree = DeviceTree(
         levels=tuple(Level(mbrs=m, parent=p)
                      for m, p in zip(mbrs, parents)),
-        leaf_entries=jnp.zeros((L, 8, 2), jnp.float32),
+        leaf_entries=jnp.zeros((L, 2, 8), jnp.float32),
         leaf_entry_ids=jnp.zeros((L, 8), jnp.int32),
         leaf_counts=jnp.zeros((L,), jnp.int32),
         n_points=0, max_entries=4)

@@ -39,7 +39,7 @@ def _device_tree(lm, lp, sl):
     L = lm[-1].shape[0]
     return DeviceTree(
         levels=tuple(Level(mbrs=m, parent=p) for m, p in zip(lm, lp)),
-        leaf_entries=jnp.full((L, 8, 2), jnp.inf, jnp.float32),
+        leaf_entries=jnp.full((L, 2, 8), jnp.inf, jnp.float32),
         leaf_entry_ids=jnp.full((L, 8), -1, jnp.int32),
         leaf_counts=jnp.zeros((L,), jnp.int32),
         n_points=0, max_entries=8, aslices=sl)
