@@ -182,12 +182,15 @@ def _refine_and_flag(ait: AITree, tree: DeviceTree, queries: jnp.ndarray,
     evidence against the query's cell) apart from the structural fallbacks.
     """
     pred_over = n_pred > ait.max_pred
-    ref = traversal.refine_leaves(tree, queries, leaf_idx, valid,
-                                  use_kernel=use_kernel)
+    with jax.named_scope("refine"):
+        ref = traversal.refine_leaves(tree, queries, leaf_idx, valid,
+                                      use_kernel=use_kernel)
     empty = n_pred == 0
     # paper's misprediction signal: a predicted leaf with no qualifying entry
     mispredict = jnp.any((ref.counts == 0) & valid, axis=-1)
-    result_ids, trunc = traversal.gather_result_ids(tree, ref, max_results)
+    with jax.named_scope("gather_ids"):
+        result_ids, trunc = traversal.gather_result_ids(tree, ref,
+                                                        max_results)
     fallback = empty | mispredict | cell_over | pred_over | trunc
     n_results = jnp.sum(ref.counts * valid.astype(jnp.int32), axis=-1)
     return (ref.counts, jnp.minimum(n_pred, ait.max_pred), n_results,
@@ -277,9 +280,10 @@ def ai_query_compact(ait: AITree, tree: DeviceTree, queries: jnp.ndarray, *,
     (exact-fit evaluation, labels).
     """
     queries = queries.astype(jnp.float32)
-    leaf_idx, valid, n_pred, cell_over = predict_compact(
-        ait, queries, tree.n_leaves, use_kernel=use_kernel,
-        tile_b=tile_b, tile_l=tile_l)
+    with jax.named_scope("predict"):
+        leaf_idx, valid, n_pred, cell_over = predict_compact(
+            ait, queries, tree.n_leaves, use_kernel=use_kernel,
+            tile_b=tile_b, tile_l=tile_l)
     counts, n_pred_c, n_results, result_ids, fallback, mis = \
         _refine_and_flag(ait, tree, queries, leaf_idx, valid, n_pred,
                          cell_over, max_results, use_kernel)

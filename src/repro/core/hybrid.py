@@ -124,57 +124,67 @@ def hybrid_query(h: HybridTree, queries: jnp.ndarray, *,
     queries = queries.astype(jnp.float32)
     B = queries.shape[0]
 
-    if force_path == "r":
-        high = jnp.zeros((B,), bool)
-    elif force_path == "ai":
-        high = jnp.ones((B,), bool)
-    else:
-        high = route_high(h.router, queries)
+    # device phases (``jax.named_scope``: op-name metadata only, so the
+    # profiler names each op's phase and no output bit changes): route,
+    # guard, ai, r, select; ai and r hold their own sub-phases
+    with jax.named_scope("route"):
+        if force_path == "r":
+            high = jnp.zeros((B,), bool)
+        elif force_path == "ai":
+            high = jnp.ones((B,), bool)
+        else:
+            high = route_high(h.router, queries)
 
-    if guard and force_path == "auto":
-        demoted = high & guard_demoted(h.ait, queries)
-    else:
-        demoted = jnp.zeros((B,), bool)
-    eligible = high & ~demoted
+    with jax.named_scope("guard"):
+        if guard and force_path == "auto":
+            demoted = high & guard_demoted(h.ait, queries)
+        else:
+            demoted = jnp.zeros((B,), bool)
+        eligible = high & ~demoted
 
     # serving-path compact AI query: prediction lands in the [B, max_pred]
     # slot table (bit-identical to the dense ai_query on all shared fields;
     # the [B, L] score table exists only on the kernel-free oracle rung)
-    ai = ai_query_compact(h.ait, h.tree, queries, max_results=max_results,
-                          use_kernel=use_kernel)
+    with jax.named_scope("ai"):
+        ai = ai_query_compact(h.ait, h.tree, queries,
+                              max_results=max_results, use_kernel=use_kernel)
     # serving-path R query: the traversal kernel's compaction epilogue
     # hands the visited slots to refinement (per-field bit-identical to
     # the dense-mask range_query)
-    r = traversal.range_query_compact(h.tree, queries,
-                                      max_visited=max_visited,
-                                      max_results=max_results,
-                                      use_kernel=use_kernel)
-
-    used_ai = eligible & ~ai.fallback
-    n_results = jnp.where(used_ai, ai.n_results, r.n_results)
-    result_ids = jnp.where(used_ai[:, None], ai.result_ids, r.result_ids)
-    # cost accounting (paper §IV-A): AI path pays prediction + its accesses;
-    # a fallback additionally pays the classical visit set. Guard-demoted
-    # rows never reach prediction, so they pay the classical cost only.
-    leaf_accesses = jnp.where(
-        eligible,
-        ai.n_pred + jnp.where(ai.fallback, r.n_visited, 0),
-        r.n_visited,
-    )
-    return HybridResult(
-        routed_high=high,
-        used_ai=used_ai,
-        n_results=n_results,
-        result_ids=result_ids,
-        leaf_accesses=leaf_accesses,
-        n_visited_r=r.n_visited,
-        n_true=r.n_true,
-        # only flag rows the R path answered — used_ai rows are exact
-        # (AI-side truncation already forces fallback)
-        truncated=r.truncated & ~used_ai,
-        guarded=demoted,
-        # only rows that actually attempted the AI path can mispredict —
-        # drift evidence must not be charged to guarded/low-overlap rows
-        mispredict=eligible & ai.mispredict,
-        cell_id=ai.cell_id,
-    )
+    with jax.named_scope("r"):
+        r = traversal.range_query_compact(h.tree, queries,
+                                          max_visited=max_visited,
+                                          max_results=max_results,
+                                          use_kernel=use_kernel)
+    with jax.named_scope("select"):
+        used_ai = eligible & ~ai.fallback
+        n_results = jnp.where(used_ai, ai.n_results, r.n_results)
+        result_ids = jnp.where(used_ai[:, None], ai.result_ids,
+                               r.result_ids)
+        # cost accounting (paper §IV-A): AI path pays prediction + its
+        # accesses; a fallback additionally pays the classical visit set.
+        # Guard-demoted rows never reach prediction, so they pay the
+        # classical cost only.
+        leaf_accesses = jnp.where(
+            eligible,
+            ai.n_pred + jnp.where(ai.fallback, r.n_visited, 0),
+            r.n_visited,
+        )
+        return HybridResult(
+            routed_high=high,
+            used_ai=used_ai,
+            n_results=n_results,
+            result_ids=result_ids,
+            leaf_accesses=leaf_accesses,
+            n_visited_r=r.n_visited,
+            n_true=r.n_true,
+            # only flag rows the R path answered — used_ai rows are exact
+            # (AI-side truncation already forces fallback)
+            truncated=r.truncated & ~used_ai,
+            guarded=demoted,
+            # only rows that actually attempted the AI path can mispredict
+            # — drift evidence must not be charged to guarded/low-overlap
+            # rows
+            mispredict=eligible & ai.mispredict,
+            cell_id=ai.cell_id,
+        )

@@ -18,14 +18,28 @@ re-served on a wide-bound tier, instead of being the caller's problem.
 
 Everything here is host-side orchestration (numpy permutations around
 jit'd serve steps); the device-side work stays in the serve step itself.
+
+``serve_workload`` writes host spans (``telemetry.span``) into the
+profiler's trace, each tagged with the call's ``request`` id and its
+``tier`` (``narrow``/``wide``): ``serve.request`` around the call,
+``serve.keys`` (curve keys, a device round trip), ``serve.sort``
+(argsort, inverse, batch padding), ``serve.step`` and ``serve.pull`` per
+batch, ``serve.unpermute``, ``serve.wide`` around the wide tier's pass
+and ``serve.merge``. ``ServeReport`` counts the pad rows of both tiers
+and the bytes the steps return; ``SERVED`` keeps the newest calls'
+reports, without their stats, for readers that total a window of them.
 """
 from __future__ import annotations
 
+import itertools
+from collections import deque
 from typing import Callable, Iterator, NamedTuple, Optional
 
 import numpy as np
 import jax
 import jax.numpy as jnp
+
+from repro.core.telemetry import span
 
 
 SORT_MODES = ("none", "morton", "hilbert")
@@ -101,6 +115,11 @@ class Schedule(NamedTuple):
     sort: str
 
 
+def _check_stream(n: int, batch: int) -> None:
+    if n == 0 or batch <= 0:
+        raise ValueError(f"need n_queries > 0 and batch > 0, got {n}/{batch}")
+
+
 def make_schedule(queries: np.ndarray, batch: int, sort: str = "hilbert",
                   bbox: Optional[np.ndarray] = None) -> Schedule:
     """Key-sorted batch formation. ``sort="none"`` keeps submission order.
@@ -109,10 +128,13 @@ def make_schedule(queries: np.ndarray, batch: int, sort: str = "hilbert",
     submission order — scheduling is always a pure permutation.
     """
     q = np.asarray(queries, np.float32)
-    n = q.shape[0]
-    if n == 0 or batch <= 0:
-        raise ValueError(f"need n_queries > 0 and batch > 0, got {n}/{batch}")
-    keys = spatial_keys(q, sort, bbox)
+    _check_stream(q.shape[0], batch)
+    return _schedule_from_keys(spatial_keys(q, sort, bbox), batch, sort)
+
+
+def _schedule_from_keys(keys: np.ndarray, batch: int, sort: str
+                        ) -> Schedule:
+    n = keys.shape[0]
     order = np.argsort(keys, kind="stable").astype(np.int32)
     inv = np.empty_like(order)
     inv[order] = np.arange(n, dtype=np.int32)
@@ -177,6 +199,46 @@ class ServeReport(NamedTuple):
     n_reserved: int         # rows re-served on the wide tier
     wide_batches: int
     sort: str
+    pad_rows: int = 0       # n_batches·batch − n_queries: padding served
+    wide_pad_rows: int = 0  # wide_batches·batch − n_reserved
+    pulled_bytes: int = 0   # summed nbytes of every stats array the
+    #                         steps of both tiers returned
+
+
+# one id per top-level ``serve_workload`` call in the process, carried by
+# every span of the call (both tiers)
+_request_ids = itertools.count()
+# the reports of the newest top-level ``serve_workload`` calls in the
+# process, oldest first, each with ``stats=None``
+SERVED: deque = deque(maxlen=4096)
+
+
+def _serve_tier(serve_fn: Callable, q: np.ndarray, batch: int, sort: str,
+                bbox: Optional[np.ndarray], request: int, tier: str
+                ) -> tuple:
+    """One tier's pass over a stream of [Q, 4] f32 queries: keys, sort
+    and pad, one step per batch, pull, back to submission order. Returns
+    ``(stats, schedule, pulled bytes)``."""
+    n = q.shape[0]
+    _check_stream(n, batch)
+    tag = {"request": request, "tier": tier}
+    with span("serve.keys", rows=n, **tag):
+        keys = spatial_keys(q, sort, bbox)
+    with span("serve.sort", rows=n, **tag):
+        sched = _schedule_from_keys(keys, batch, sort)
+        chunks = list(iter_batches(q, sched))
+    outs, pulled = [], 0
+    for b, (chunk, n_valid) in enumerate(chunks):
+        with span("serve.step", batch=b, rows=n_valid, **tag):
+            stats = serve_fn(jnp.asarray(chunk))
+        with span("serve.pull", batch=b, **tag):
+            stats = jax.tree.map(np.asarray, stats)
+        pulled += sum(a.nbytes for a in jax.tree.leaves(stats))
+        outs.append(_rows(stats, np.s_[:n_valid]))
+    with span("serve.unpermute", rows=n, **tag):
+        stream = jax.tree.map(lambda *xs: np.concatenate(xs, axis=0), *outs)
+        stats = _rows(stream, sched.inv)    # back to submission order
+    return stats, sched, pulled
 
 
 def serve_workload(serve_fn: Callable, queries: np.ndarray, *, batch: int,
@@ -200,29 +262,38 @@ def serve_workload(serve_fn: Callable, queries: np.ndarray, *, batch: int,
     width — see ``_merge_rows``). ``trunc_field=None`` (or absent from
     the stats) disables the second tier.
     """
-    sched = make_schedule(queries, batch, sort, bbox)
-    outs = []
-    for chunk, n_valid in iter_batches(queries, sched):
-        stats = serve_fn(jnp.asarray(chunk))
-        outs.append(_rows(stats, np.s_[:n_valid]))
-    stream = jax.tree.map(lambda *xs: np.concatenate(xs, axis=0), *outs)
-    result = _rows(stream, sched.inv)   # back to submission order
-
-    n_reserved = wide_batches = 0
-    if wide_fn is not None and trunc_field is not None \
-            and hasattr(result, trunc_field):
-        trunc = np.asarray(getattr(result, trunc_field)).astype(bool)
-        idx = np.flatnonzero(trunc)
-        n_reserved = int(idx.size)
-        if n_reserved:
-            wide = serve_workload(wide_fn, np.asarray(queries, np.float32)[idx],
-                                  batch=batch, sort=sort, bbox=bbox,
-                                  wide_fn=None, trunc_field=None)
-            wide_batches = wide.n_batches
-            result = _merge_rows(result, wide.stats, idx)
-    return ServeReport(stats=result, n_queries=sched.n_queries,
-                       n_batches=sched.n_batches, n_reserved=n_reserved,
-                       wide_batches=wide_batches, sort=sort)
+    q = np.asarray(queries, np.float32)
+    request = next(_request_ids)
+    with span("serve.request", request=request, tier="narrow",
+              rows=q.shape[0]):
+        result, sched, pulled = _serve_tier(serve_fn, q, batch, sort, bbox,
+                                            request, "narrow")
+        n_reserved = wide_batches = 0
+        if wide_fn is not None and trunc_field is not None \
+                and hasattr(result, trunc_field):
+            trunc = np.asarray(getattr(result, trunc_field)).astype(bool)
+            idx = np.flatnonzero(trunc)
+            n_reserved = int(idx.size)
+            if n_reserved:
+                tag = {"request": request, "tier": "wide",
+                       "rows": n_reserved}
+                with span("serve.wide", **tag):
+                    wide, wsched, wpulled = _serve_tier(
+                        wide_fn, q[idx], batch, sort, bbox, request, "wide")
+                wide_batches = wsched.n_batches
+                pulled += wpulled
+                with span("serve.merge", **tag):
+                    result = _merge_rows(result, wide, idx)
+    report = ServeReport(stats=None, n_queries=sched.n_queries,
+                         n_batches=sched.n_batches, n_reserved=n_reserved,
+                         wide_batches=wide_batches, sort=sort,
+                         pad_rows=sched.n_batches * sched.batch
+                         - sched.n_queries,
+                         wide_pad_rows=wide_batches * sched.batch
+                         - n_reserved,
+                         pulled_bytes=pulled)
+    SERVED.append(report)
+    return report._replace(stats=result)
 
 
 def visible_segments(report: "MixedReport", base_points: np.ndarray):

@@ -1,6 +1,7 @@
-"""Shared streaming-stats primitives for the serving side.
+"""Shared streaming-stats primitives for the serving side, and the one
+helper that writes the program's host spans (``span``).
 
-Two consumers, one implementation:
+Two consumers of the stats, one implementation:
 
 * ``core.monitor.FreshnessMonitor`` aggregates per-cell serve counters
   over a bounded window of serve segments and summarizes them with
@@ -15,6 +16,11 @@ Everything here is host-side numpy — these run between jit'd serve
 steps, never inside one — and deterministic: the reservoir's eviction
 RNG is seeded, so two runs over the same stream report the same
 quantiles.
+
+``span`` opens a host span in the profiler's own trace
+(``jax.profiler.TraceAnnotation``), so the spans share the device
+trace's clock; while no trace is being taken it records nothing and
+formats none of its attributes.
 """
 from __future__ import annotations
 
@@ -22,6 +28,17 @@ from collections import deque
 from typing import Optional, Sequence
 
 import numpy as np
+from jax.profiler import TraceAnnotation
+
+# what ``span`` opens: ``factory(name, **attrs)`` → context manager
+span_factory = TraceAnnotation
+
+
+def span(name: str, **attrs):
+    """A context manager that holds host span ``name`` open, with
+    ``attrs`` (ints and strings) as the span's stats. Spans opened inside
+    it on the same thread are its children."""
+    return span_factory(name, **attrs)
 
 
 class Ewma:

@@ -345,11 +345,14 @@ def range_query_compact(tree: DeviceTree, queries: jnp.ndarray, *,
     ``n_results``/``result_ids``/``truncated`` and the compacted slots).
     """
     queries = queries.astype(jnp.float32)
-    cv = visited_leaves_compact(tree, queries, max_visited,
-                                use_kernel=use_kernel,
-                                tile_b=tile_b, tile_l=tile_l)
-    ref = refine_leaves(tree, queries, cv.leaf_idx, cv.valid, use_kernel)
-    result_ids, trunc_r = gather_result_ids(tree, ref, max_results)
+    with jax.named_scope("traverse"):
+        cv = visited_leaves_compact(tree, queries, max_visited,
+                                    use_kernel=use_kernel,
+                                    tile_b=tile_b, tile_l=tile_l)
+    with jax.named_scope("refine"):
+        ref = refine_leaves(tree, queries, cv.leaf_idx, cv.valid, use_kernel)
+    with jax.named_scope("gather_ids"):
+        result_ids, trunc_r = gather_result_ids(tree, ref, max_results)
     validi = cv.valid.astype(jnp.int32)
     return CompactQueryResult(
         leaf_idx=cv.leaf_idx,

@@ -474,26 +474,12 @@ def serve_range(fns: ServeFns, wl, args
     resid = int(np.asarray(getattr(st, fns.trunc_field)).sum())
     print(f"# stream: {report.n_queries} queries in {report.n_batches} "
           f"batches (sort={report.sort}), {report.n_reserved} re-served "
-          f"wide ({report.wide_batches} batches), {resid} still truncated")
+          f"wide ({report.wide_batches} batches), {resid} still truncated; "
+          f"pad rows {report.pad_rows} narrow, {report.wide_pad_rows} "
+          f"wide; {report.pulled_bytes} B pulled to the host")
     print(f"# serve: {report.n_queries/dt_s:.0f} queries/s, "
           f"{acc:.2f} leaf accesses/query, "
           f"{100*ai:.1f}% answered by the AI path")
-    # AI-path fusion accounting: with the fused prediction kernel (mlp
-    # bank + --kernel) prediction flows through the compact [B, max_pred]
-    # slot table and the dense [B, L] score table never materializes;
-    # every other configuration still runs the dense-oracle rung, so
-    # report the saving only when it actually happened.
-    k = fns.hybrid.ait.max_pred
-    n_leaves = fns.hybrid.tree.n_leaves
-    dense_b = report.n_queries * n_leaves * 4
-    slot_b = report.n_queries * (k + 1) * 4
-    verdict = ("eliminated" if fns.ai_fused else
-               "still materialized on this config — fused path needs "
-               "--classifier mlp --kernel (and the kernel dispatch "
-               "active)")
-    print(f"# AI path: {slot_b/1e3:.0f} KB compact slot tables; "
-          f"{dense_b/1e6:.1f} MB dense [B, {n_leaves}] score "
-          f"tables {verdict}")
     # no-drop oracle: the labelling pass already executed every query
     mism = int(np.sum(np.asarray(st.n_results) != wl.n_results))
     print(f"# oracle: {mism} / {report.n_queries} n_results mismatches "

@@ -421,3 +421,135 @@ def test_two_tier_final_ragged_batch_all_overflow():
                                    max_results=256, use_kernel=False)
     np.testing.assert_array_equal(np.asarray(rep.stats.n_results),
                                   np.asarray(oracle.n_results))
+
+
+# ---------------------------------------------------------------------------
+# host spans and counters of serve_workload
+# ---------------------------------------------------------------------------
+
+import contextlib
+
+from repro.core import telemetry
+
+
+class _SpanRecorder:
+    """Stands in for the profiler's span factory: records each span's
+    name, attributes and parent (the span open around it)."""
+
+    def __init__(self):
+        self.spans = []     # [(name, attrs, parent index or None)]
+        self._open = []
+
+    @contextlib.contextmanager
+    def __call__(self, name, **attrs):
+        self.spans.append((name, attrs,
+                           self._open[-1] if self._open else None))
+        self._open.append(len(self.spans) - 1)
+        try:
+            yield
+        finally:
+            self._open.pop()
+
+
+def _tier_spans(tier, request, rows, batch, parent):
+    """The spans one tier's pass over ``rows`` queries writes."""
+    n_batches = -(-rows // batch)
+    tag = {"request": request, "tier": tier}
+    out = [("serve.keys", dict(rows=rows, **tag), parent),
+           ("serve.sort", dict(rows=rows, **tag), parent)]
+    for b in range(n_batches):
+        out += [("serve.step",
+                 dict(batch=b, rows=min(batch, rows - b * batch), **tag),
+                 parent),
+                ("serve.pull", dict(batch=b, **tag), parent)]
+    return out + [("serve.unpermute", dict(rows=rows, **tag), parent)]
+
+
+def _two_tier_stream():
+    """60 queries, batch 16: the narrow bound k=4 truncates 17 of them,
+    which the wide tier (k=64) re-serves."""
+    tree = _tree64()
+    q = _queries(60, seed=4, big_frac=0.4)
+    narrow = _serve_fn(tree, k=4, max_results=256)
+    wide = _serve_fn(tree, k=64, max_results=256)
+    trunc = np.asarray(schedule.serve_workload(
+        narrow, q, batch=16, sort="hilbert").stats.truncated)
+    assert int(trunc.sum()) == 17, "fixture changed"
+    return q, narrow, wide
+
+
+def test_serve_spans_name_tag_and_nest(monkeypatch):
+    q, narrow, wide = _two_tier_stream()
+    rec = _SpanRecorder()
+    monkeypatch.setattr(telemetry, "span_factory", rec)
+    schedule.serve_workload(narrow, q, batch=16, sort="hilbert",
+                            wide_fn=wide, trunc_field="truncated")
+    request = rec.spans[0][1]["request"]
+    tag = {"request": request, "tier": "wide", "rows": 17}
+    want = [("serve.request", {"request": request, "tier": "narrow",
+                               "rows": 60}, None)]
+    want += _tier_spans("narrow", request, 60, 16, 0)
+    wide_at = len(want)
+    want += [("serve.wide", tag, 0)]
+    want += _tier_spans("wide", request, 17, 16, wide_at)
+    want += [("serve.merge", tag, 0)]
+    assert rec.spans == want
+    # the next call takes a new request id
+    rec.spans.clear()
+    schedule.serve_workload(narrow, q[:5], batch=16, sort="hilbert")
+    assert rec.spans[0][1]["request"] != request
+    assert {a["request"] for _, a, _ in rec.spans} == \
+        {rec.spans[0][1]["request"]}
+
+
+def test_serve_spans_without_a_wide_batch(monkeypatch):
+    """Nothing truncates: the wide tier writes no span."""
+    tree = _tree64()
+    q = _queries(40, seed=6)            # small rects: k=64 never overflows
+    narrow = _serve_fn(tree, k=64, max_results=256)
+    rec = _SpanRecorder()
+    monkeypatch.setattr(telemetry, "span_factory", rec)
+    rep = schedule.serve_workload(narrow, q, batch=16, sort="hilbert",
+                                  wide_fn=narrow, trunc_field="truncated")
+    assert rep.wide_batches == 0
+    request = rec.spans[0][1]["request"]
+    assert rec.spans == [("serve.request", {"request": request,
+                                            "tier": "narrow", "rows": 40},
+                          None)] + _tier_spans("narrow", request, 40, 16, 0)
+    # 3 batches of 16 rows for 40 queries; each row of a k=64,
+    # max_results=256 step returns 5·64 + 4·256 + 13 bytes
+    assert (rep.pad_rows, rep.wide_pad_rows) == (8, 0)
+    assert rep.pulled_bytes == 3 * 16 * (5 * 64 + 4 * 256 + 13)
+
+
+def test_serve_report_counts_pad_rows_and_pulled_bytes():
+    q, narrow, wide = _two_tier_stream()
+    rep = schedule.serve_workload(narrow, q, batch=16, sort="hilbert",
+                                  wide_fn=wide, trunc_field="truncated")
+    assert (rep.n_batches, rep.n_reserved, rep.wide_batches) == (4, 17, 2)
+    assert rep.pad_rows == 4 * 16 - 60
+    assert rep.wide_pad_rows == 2 * 16 - 17
+    # a row of range_query_compact at bound k and max_results m returns
+    # leaf_idx i32[k], valid bool[k], three i32 counts, result_ids
+    # i32[m] and one bool: 5k + 4m + 13 bytes
+    narrow_row, wide_row = 5 * 4 + 4 * 256 + 13, 5 * 64 + 4 * 256 + 13
+    assert (narrow_row, wide_row) == (1057, 1357)
+    assert rep.pulled_bytes == 4 * 16 * narrow_row + 2 * 16 * wide_row
+    # one tier alone: no wide rows, no wide padding
+    alone = schedule.serve_workload(narrow, q, batch=16, sort="hilbert")
+    assert (alone.pad_rows, alone.wide_pad_rows, alone.pulled_bytes) == \
+        (4, 0, 4 * 16 * narrow_row)
+
+
+def test_served_log_keeps_each_calls_counts_without_stats():
+    q, narrow, wide = _two_tier_stream()
+    before = len(schedule.SERVED)
+    reps = [schedule.serve_workload(narrow, q, batch=16, sort="hilbert",
+                                    wide_fn=wide, trunc_field="truncated"),
+            schedule.serve_workload(narrow, q[:20], batch=16, sort="none")]
+    # one entry per top-level call (the wide tier adds none), newest last
+    assert len(schedule.SERVED) == min(before + 2, schedule.SERVED.maxlen)
+    logged = list(schedule.SERVED)[-2:]
+    assert all(r.stats is None for r in logged)
+    assert logged == [r._replace(stats=None) for r in reps]
+    assert (logged[1].pad_rows, logged[1].wide_pad_rows) == (12, 0)
