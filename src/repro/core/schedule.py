@@ -25,9 +25,10 @@ profiler's trace, each tagged with the call's ``request`` id and its
 ``serve.keys`` (curve keys, a device round trip), ``serve.sort``
 (argsort, inverse, batch padding), ``serve.step`` and ``serve.pull`` per
 batch, ``serve.unpermute``, ``serve.wide`` around the wide tier's pass
-and ``serve.merge``. ``ServeReport`` counts the pad rows of both tiers
-and the bytes the steps return; ``SERVED`` keeps the newest calls'
-reports, without their stats, for readers that total a window of them.
+and ``serve.merge``. ``ServeReport`` counts the pad rows of both tiers,
+the bytes the steps return and the chunks their result-id gathers
+searched; ``SERVED`` keeps the newest calls' reports, without their
+stats, for readers that total a window of them.
 """
 from __future__ import annotations
 
@@ -40,6 +41,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.telemetry import span
+from repro.core.traversal import GATHER_CHUNK
 
 
 SORT_MODES = ("none", "morton", "hilbert")
@@ -203,6 +205,9 @@ class ServeReport(NamedTuple):
     wide_pad_rows: int = 0  # wide_batches·batch − n_reserved
     pulled_bytes: int = 0   # summed nbytes of every stats array the
     #                         steps of both tiers returned
+    gather_chunks: int = 0  # chunks of GATHER_CHUNK slots the answering
+    #                         path's result-id gather searched, summed
+    #                         over the steps of both tiers
 
 
 # one id per top-level ``serve_workload`` call in the process, carried by
@@ -213,12 +218,29 @@ _request_ids = itertools.count()
 SERVED: deque = deque(maxlen=4096)
 
 
+def _gather_chunks(stats) -> int:
+    """Chunks of ``GATHER_CHUNK`` result slots that one step's answering
+    path searched in ``traversal.gather_result_ids``, reckoned from the
+    step's pulled stats: ceil(min(max hits, max_results) / C), the hits
+    being ``n_results`` less any delta-buffer hits folded into it. It
+    counts the answering path only: where the dispatch is masked
+    (``hybrid_query``), the other path's gather runs too, on its own hit
+    counts. 0 for stats without a result-id table."""
+    ids = getattr(stats, "result_ids", None)
+    if ids is None:
+        return 0
+    hits = np.asarray(stats.n_results) - np.asarray(
+        getattr(stats, "delta_hits", 0))
+    top = min(int(np.max(hits, initial=0)), ids.shape[1])
+    return -(-top // GATHER_CHUNK)
+
+
 def _serve_tier(serve_fn: Callable, q: np.ndarray, batch: int, sort: str,
                 bbox: Optional[np.ndarray], request: int, tier: str
                 ) -> tuple:
     """One tier's pass over a stream of [Q, 4] f32 queries: keys, sort
     and pad, one step per batch, pull, back to submission order. Returns
-    ``(stats, schedule, pulled bytes)``."""
+    ``(stats, schedule, pulled bytes, gather chunks)``."""
     n = q.shape[0]
     _check_stream(n, batch)
     tag = {"request": request, "tier": tier}
@@ -227,18 +249,19 @@ def _serve_tier(serve_fn: Callable, q: np.ndarray, batch: int, sort: str,
     with span("serve.sort", rows=n, **tag):
         sched = _schedule_from_keys(keys, batch, sort)
         chunks = list(iter_batches(q, sched))
-    outs, pulled = [], 0
+    outs, pulled, chunked = [], 0, 0
     for b, (chunk, n_valid) in enumerate(chunks):
         with span("serve.step", batch=b, rows=n_valid, **tag):
             stats = serve_fn(jnp.asarray(chunk))
         with span("serve.pull", batch=b, **tag):
             stats = jax.tree.map(np.asarray, stats)
         pulled += sum(a.nbytes for a in jax.tree.leaves(stats))
+        chunked += _gather_chunks(stats)
         outs.append(_rows(stats, np.s_[:n_valid]))
     with span("serve.unpermute", rows=n, **tag):
         stream = jax.tree.map(lambda *xs: np.concatenate(xs, axis=0), *outs)
         stats = _rows(stream, sched.inv)    # back to submission order
-    return stats, sched, pulled
+    return stats, sched, pulled, chunked
 
 
 def serve_workload(serve_fn: Callable, queries: np.ndarray, *, batch: int,
@@ -266,8 +289,8 @@ def serve_workload(serve_fn: Callable, queries: np.ndarray, *, batch: int,
     request = next(_request_ids)
     with span("serve.request", request=request, tier="narrow",
               rows=q.shape[0]):
-        result, sched, pulled = _serve_tier(serve_fn, q, batch, sort, bbox,
-                                            request, "narrow")
+        result, sched, pulled, chunked = _serve_tier(
+            serve_fn, q, batch, sort, bbox, request, "narrow")
         n_reserved = wide_batches = 0
         if wide_fn is not None and trunc_field is not None \
                 and hasattr(result, trunc_field):
@@ -278,10 +301,11 @@ def serve_workload(serve_fn: Callable, queries: np.ndarray, *, batch: int,
                 tag = {"request": request, "tier": "wide",
                        "rows": n_reserved}
                 with span("serve.wide", **tag):
-                    wide, wsched, wpulled = _serve_tier(
+                    wide, wsched, wpulled, wchunked = _serve_tier(
                         wide_fn, q[idx], batch, sort, bbox, request, "wide")
                 wide_batches = wsched.n_batches
                 pulled += wpulled
+                chunked += wchunked
                 with span("serve.merge", **tag):
                     result = _merge_rows(result, wide, idx)
     report = ServeReport(stats=None, n_queries=sched.n_queries,
@@ -291,7 +315,7 @@ def serve_workload(serve_fn: Callable, queries: np.ndarray, *, batch: int,
                          - sched.n_queries,
                          wide_pad_rows=wide_batches * sched.batch
                          - n_reserved,
-                         pulled_bytes=pulled)
+                         pulled_bytes=pulled, gather_chunks=chunked)
     SERVED.append(report)
     return report._replace(stats=result)
 

@@ -240,28 +240,53 @@ def scatter_rows(base: jnp.ndarray, idx: jnp.ndarray,
     return base.at[rows, idx].max(vals)
 
 
+# target slots one step of ``gather_result_ids``'s loop searches: one lane
+# width
+GATHER_CHUNK = 128
+
+
 def gather_result_ids(tree: DeviceTree, refine: RefineResult,
                       max_results: int) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Flatten qualifying entry ids to [B, max_results] (padded with -1).
 
     Sort-free, same scheme as ``compact_mask``: the ``j``-th qualifying
     entry's flat (leaf-slot, entry) position is a rowwise binary search of
-    ``j + 1`` over the inclusive prefix count; entries past the bound are
-    simply never searched for.
+    ``j + 1`` over the inclusive prefix count ``cs``. Only the slots some
+    row of the batch can fill are searched: a ``lax.while_loop`` walks
+    chunks of ``GATHER_CHUNK`` target slots and stops once the chunk start
+    passes ``min(max(n_in), max_results)``, the batch's largest hit count;
+    slots past it keep the -1 the output starts from. A last chunk that
+    would run past ``max_results`` starts at ``max_results − C'``
+    (``C' = min(GATHER_CHUNK, max_results)``), so the slots it shares with
+    the chunk before are written again with the same values. Each slot's
+    value is the one a search of every slot would give: the ids, the -1
+    padding and the truncation flag are bit-identical to the
+    ``gather_result_ids_topk`` oracle's, whatever the batch's hit counts.
     """
-    ids = tree.leaf_entry_ids[refine.leaf_idx]              # [B, K, M]
-    B = ids.shape[0]
-    flat_ids = ids.reshape(B, -1)
+    B, K, M = refine.inside.shape
     flat_in = refine.inside.reshape(B, -1).astype(jnp.int32)
     cs = jnp.cumsum(flat_in, axis=-1)
-    targets = jnp.arange(1, max_results + 1, dtype=jnp.int32)
-    pos = jax.vmap(
-        lambda c: jnp.searchsorted(c, targets, side="left"))(cs)
-    rows = jnp.arange(B, dtype=jnp.int32)[:, None]
     n_in = cs[:, -1]
-    valid = targets[None, :] <= n_in[:, None]
-    safe = jnp.minimum(pos, flat_ids.shape[-1] - 1).astype(jnp.int32)
-    out = jnp.where(valid, flat_ids[rows, safe], -1)
+    width = min(GATHER_CHUNK, max_results)
+    stop = jnp.minimum(jnp.max(n_in, initial=0), max_results)
+    rows = jnp.arange(B, dtype=jnp.int32)[:, None]
+    search = jax.vmap(lambda c, t: jnp.searchsorted(c, t, side="left"),
+                      in_axes=(0, None))
+
+    def chunk(carry):
+        c, out = carry
+        start = jnp.minimum(c * GATHER_CHUNK, max_results - width)
+        targets = start + 1 + jnp.arange(width, dtype=jnp.int32)
+        pos = search(cs, targets)
+        safe = jnp.minimum(pos, K * M - 1).astype(jnp.int32)
+        ids = tree.leaf_entry_ids[refine.leaf_idx[rows, safe // M],
+                                  safe % M]
+        vals = jnp.where(targets[None, :] <= n_in[:, None], ids, -1)
+        return c + 1, jax.lax.dynamic_update_slice(out, vals, (0, start))
+
+    out0 = jnp.full((B, max_results), -1, tree.leaf_entry_ids.dtype)
+    _, out = jax.lax.while_loop(lambda carry: carry[0] * GATHER_CHUNK < stop,
+                                chunk, (jnp.int32(0), out0))
     trunc = n_in > max_results
     return out, trunc
 

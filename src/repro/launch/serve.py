@@ -476,7 +476,8 @@ def serve_range(fns: ServeFns, wl, args
           f"batches (sort={report.sort}), {report.n_reserved} re-served "
           f"wide ({report.wide_batches} batches), {resid} still truncated; "
           f"pad rows {report.pad_rows} narrow, {report.wide_pad_rows} "
-          f"wide; {report.pulled_bytes} B pulled to the host")
+          f"wide; {report.pulled_bytes} B pulled to the host; "
+          f"{report.gather_chunks} result-id gather chunks")
     print(f"# serve: {report.n_queries/dt_s:.0f} queries/s, "
           f"{acc:.2f} leaf accesses/query, "
           f"{100*ai:.1f}% answered by the AI path")

@@ -8,14 +8,15 @@ Pins down conventions that previously lived only in docstrings:
 * ``compact_mask`` / ``compact_mask_counted`` at the overflow boundary —
   rows with exactly ``k``, ``k ± 1`` set bits, against the ``top_k``
   oracle;
-* ``gather_result_ids`` at exactly ``max_results`` qualifying entries,
-  against its ``top_k`` oracle.
+* ``gather_result_ids`` at exactly ``max_results`` qualifying entries
+  and at the edges of its loop's chunks, against its ``top_k`` oracle.
 
 Runs under real hypothesis when installed, else the fixed-seed example
 fallback in ``tests/helpers/hypo.py``.
 """
 import numpy as np
 import pytest
+import jax
 import jax.numpy as jnp
 from helpers.hypo import given, settings, st
 
@@ -138,6 +139,38 @@ def test_gather_result_ids_truncation_boundary(K, M, seed):
     np.testing.assert_array_equal(np.asarray(new_tr), np.asarray(old_tr))
     np.testing.assert_array_equal(
         np.asarray(new_tr), [False, False, min(K * M, mr + 1) > mr])
+
+
+_C = traversal.GATHER_CHUNK
+
+
+@pytest.mark.parametrize("seed", [0, 1, 2])
+@pytest.mark.parametrize("mr", [300, 64])
+def test_gather_result_ids_chunk_boundaries(mr, seed):
+    """One batch whose rows hold 0, 1, C−1, C, C+1, 2C+1, R−1, R and R+1
+    hits around the loop's chunk edges, at a ``max_results`` R that is
+    not a multiple of C (300) or is under it (64), under ``jax.jit``:
+    ids and truncation flags bit-identical to the top_k oracle."""
+    rng = np.random.default_rng(seed)
+    L, K, M = 40, 16, 20
+    counts = [0, 1, _C - 1, _C, _C + 1, 2 * _C + 1, mr - 1, mr, mr + 1]
+    inside = jnp.asarray(np.stack([
+        _mask_with_count(rng, K * M, c).reshape(K, M) for c in counts]))
+    B = len(counts)
+    leaf_idx = jnp.asarray(rng.integers(0, L, (B, K)), jnp.int32)
+    refine = traversal.RefineResult(
+        counts=jnp.sum(inside.astype(jnp.int32), -1), inside=inside,
+        leaf_idx=leaf_idx, valid=jnp.ones((B, K), bool))
+    tree = _FakeTree(rng, L, M)
+    new_ids, new_tr = jax.jit(
+        lambda r: traversal.gather_result_ids(tree, r, mr))(refine)
+    old_ids, old_tr = traversal.gather_result_ids_topk(tree, refine, mr)
+    assert new_ids.shape == old_ids.shape == (B, mr)
+    assert new_ids.dtype == old_ids.dtype
+    np.testing.assert_array_equal(np.asarray(new_ids), np.asarray(old_ids))
+    np.testing.assert_array_equal(np.asarray(new_tr), np.asarray(old_tr))
+    np.testing.assert_array_equal(np.asarray(new_tr),
+                                  np.asarray(counts) > mr)
 
 
 @pytest.mark.slow

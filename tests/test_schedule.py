@@ -6,6 +6,8 @@ key-sorted serving must be a bit-identical permutation of unsorted serving
 root == leaf tree, and the wide-tier re-serve must clear ``r_truncated``
 without touching non-overflow rows.
 """
+from typing import NamedTuple
+
 import numpy as np
 import pytest
 import jax
@@ -553,3 +555,57 @@ def test_served_log_keeps_each_calls_counts_without_stats():
     assert all(r.stats is None for r in logged)
     assert logged == [r._replace(stats=None) for r in reps]
     assert (logged[1].pad_rows, logged[1].wide_pad_rows) == (12, 0)
+
+
+class _HitStats(NamedTuple):
+    n_results: np.ndarray
+    result_ids: np.ndarray
+    truncated: np.ndarray
+
+
+def _hit_step(max_results):
+    """A step whose rows hold the hit count written in each query's first
+    coordinate, with a result-id table ``max_results`` wide."""
+    def step(q):
+        hits = np.asarray(q)[:, 0].astype(np.int32)
+        return _HitStats(
+            n_results=hits,
+            result_ids=np.zeros((hits.size, max_results), np.int32),
+            truncated=hits > max_results)
+    return step
+
+
+def test_serve_report_counts_gather_chunks():
+    """Batches of 4 in submission order, narrow table 256 wide, wide
+    table 1024: ceil(min(max hits, width) / C) chunks per step."""
+    C = traversal.GATHER_CHUNK
+    assert C == 128, "hand count below assumes 128"
+    hits = [3, 0, 1, 0,          # max 3: 1 chunk
+            127, 128, 2, 2,      # max 128: 1
+            129, 300, 2000, 7,   # max 2000, table 256: 2
+            0, 0]                # max 0 (pads repeat a 0 row): 0
+    q = np.zeros((len(hits), 4), np.float32)
+    q[:, 0] = q[:, 2] = hits
+    rep = schedule.serve_workload(_hit_step(256), q, batch=4, sort="none",
+                                  wide_fn=_hit_step(1024),
+                                  trunc_field="truncated")
+    # 300 and 2000 pass the narrow table: one wide batch, its pad rows
+    # repeating 2000, table 1024: 8 chunks
+    assert (rep.n_batches, rep.n_reserved, rep.wide_batches) == (4, 2, 1)
+    assert rep.gather_chunks == (1 + 1 + 2 + 0) + 8
+    assert list(schedule.SERVED)[-1].gather_chunks == 12
+
+
+def test_gather_chunks_one_per_step_at_few_hits():
+    """Every row of the real compact R path holds at most C hits: one
+    chunk per step of both tiers."""
+    tree = _tree64()
+    q = _queries(60, seed=4, big_frac=0.4, span=0.6)
+    rep = schedule.serve_workload(
+        _serve_fn(tree, k=4, max_results=256), q, batch=16, sort="hilbert",
+        wide_fn=_serve_fn(tree, k=64, max_results=256),
+        trunc_field="truncated")
+    assert (rep.n_batches, rep.n_reserved, rep.wide_batches) == (4, 8, 1)
+    n = np.asarray(rep.stats.n_results)
+    assert 0 < n.max() <= traversal.GATHER_CHUNK
+    assert rep.gather_chunks == rep.n_batches + rep.wide_batches == 5
