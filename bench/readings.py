@@ -4,11 +4,11 @@ and the control on a few, in one process so the deployment loads once.
     python -m bench.readings --workload gaussian-range --seconds 10 \\
         --seeds 1,2,3 --control-seeds 101,102,103
 
-The control is the program with its wide re-serve switched off
-(``schedule.serve_workload`` without ``wide_fn``): rows that overflowed
-the narrow bound keep their narrow answers, which breaks the
-configurations' guarantee that truncated rows are re-served, never
-approximated or dropped. Each run prints its compared numbers as one
+The control is the program with its wide re-serve switched off (the
+driver's ``Client(..., wide_tier=False)``: the scheduler gets no wide
+step, or no flag to re-serve by): rows that overflowed the narrow bound
+keep their narrow answers, which breaks the configurations' guarantee
+that truncated rows are re-served, never approximated or dropped. Each run prints its compared numbers as one
 ``reading`` line. The benchmark's own runs never run the control.
 """
 from __future__ import annotations

@@ -11,12 +11,15 @@ traffic mix (``bench/traffic``); its per-layer metrics are readers in
    a checkout, ``bench.deploy``), place it on the chip, and warm every
    program and shape the window drives;
 2. window: one closed-loop client sends a request, waits for all its
-   answers (spatial sort, narrow step, wide re-serve of truncated rows,
-   rows back in submission order through ``repro.core.schedule``), and
-   sends the next, until ``--seconds`` have passed; with ``--trace 1``
-   under the profiler;
+   answers, and sends the next, until ``--seconds`` have passed; with
+   ``--trace 1`` under the profiler;
 3. check: every answer of the window against the brute-force reference
    (``bench.reference``).
+
+The traffic file's ``query`` names the client driver
+(``bench/drivers/<query>.py``) that builds the server and client, warms
+them and checks the answers; this module keeps the clock, the window,
+the count of compilations in it, the trace and the result.
 
 Lines starting with ``#`` report progress. The last line of standard
 output is one JSON object; the compared numbers and their limits are the
@@ -25,9 +28,9 @@ last lines of standard error and the result's last key.
 from __future__ import annotations
 
 import argparse
+import importlib
 import importlib.util
 import json
-import math
 import os
 import shutil
 import sys
@@ -40,7 +43,6 @@ BENCH_DIR = os.path.dirname(os.path.abspath(__file__))
 SPEC_FILE = "BENCHMARK.json"
 # fixed warm-up traffic: the same set-up work for every seed
 WARM_SEED = 0
-WARM_REQUESTS = 4
 
 
 def process_start() -> float:
@@ -112,162 +114,46 @@ class Spans:
             self._open.__enter__()
 
 
-# ------------------------------------------------------------ serving
+# ------------------------------------------------------------ drivers
 
-class Server(NamedTuple):
-    """What a cell's client drives: the program's two-tier steps and the
-    arithmetic of the leaves each row's answer needs."""
-    narrow: object          # jitted [batch, 4] → stats
-    wide: object
-    trunc_field: str
-    bbox: np.ndarray        # the scheduler's fixed key frame
-    batch: int
-    sort: str
-    needed: object          # (stats, tier) → [rows] leaves the answer needs
-    state: object           # device arrays to free before the reference
-
-
-def range_server(fit, cfg: dict):
-    from repro.core import schedule
-    from repro.launch import serve
-    sv = cfg["serve"]
-    args = serve.parse_args([
-        "--classifier", cfg["bank"]["classifier"],
-        "--batch", str(sv["batch"]), "--sort", sv["sort"],
-        "--max-visited", str(sv["max_visited"]),
-        "--wide-factor", str(sv["wide_factor"])])
-    import jax
-    fns = serve.make_serve_fns(fit.hybrid, args, jax.devices()[:1])
-    bounds = {"narrow": sv["max_visited"],
-              "wide": sv["max_visited"] * sv["wide_factor"]}
-
-    def needed(st, tier):
-        # AI-path answers need their predicted leaves; R-path answers
-        # the visited leaves, as far as the step's bound reaches
-        return np.where(st.used_ai, st.leaf_accesses,
-                        np.minimum(st.n_visited_r, bounds[tier]))
-
-    print(f"# range steps: fused MLP kernel "
-          f"{'on' if fns.ai_fused else 'off'}, kernels "
-          f"{'on' if args.kernel else 'off'}", flush=True)
-    return Server(fns.narrow, fns.wide, fns.trunc_field,
-                  schedule.workload_bbox(fit.pool), sv["batch"], sv["sort"],
-                  needed, fit.hybrid)
-
-
-class Client:
-    """One closed-loop client of a ``Server``: serves a request through
-    ``repro.core.schedule.serve_workload`` and keeps the window's
-    counters and answers."""
-
-    def __init__(self, server: Server, spans: Spans, wide_tier: bool = True):
-        self.s = server
-        self.spans = spans
-        self.wide_tier = wide_tier
-        self.counters = {"queries": 0, "wide_rows": 0, "leaf_accesses": 0,
-                         "ai_rows": 0, "refine_leaves": 0}
-        self.latencies = []
-        self.answers = []
-
-    def _step(self, fn, span, calls):
-        import jax
-
-        def call(qb):
-            self.spans.phase(span)
-            out = fn(qb)
-            self.spans.phase("to_host")
-            out = jax.tree.map(np.asarray, out)
-            self.spans.phase("merge")
-            calls.append(out)
-            return out
-        return call
-
-    def serve(self, q: np.ndarray):
-        """Serve one request; returns ``(report, narrow_outs, wide_outs)``."""
-        from repro.core import schedule
-        narrow_outs, wide_outs = [], []
-        self.spans.phase("schedule")
-        rep = schedule.serve_workload(
-            self._step(self.s.narrow, "narrow_step", narrow_outs), q,
-            batch=self.s.batch, sort=self.s.sort, bbox=self.s.bbox,
-            wide_fn=(self._step(self.s.wide, "wide_step", wide_outs)
-                     if self.wide_tier else None),
-            trunc_field=self.s.trunc_field)
-        self.spans.phase(None)
-        return rep, narrow_outs, wide_outs
-
-    def record(self, q, rep, narrow_outs, wide_outs, latency: float) -> None:
-        st = rep.stats
-        c = self.counters
-        c["queries"] += q.shape[0]
-        c["wide_rows"] += rep.n_reserved
-        c["leaf_accesses"] += int(np.sum(st.leaf_accesses))
-        c["ai_rows"] += int(np.sum(st.used_ai))
-        for outs, tier, n in ((narrow_outs, "narrow", q.shape[0]),
-                              (wide_outs, "wide", rep.n_reserved)):
-            if outs:
-                cat = type(outs[0])(*(np.concatenate(f) for f in zip(*outs)))
-                c["refine_leaves"] += int(
-                    np.sum(self.s.needed(cat, tier)[:n]))
-        self.latencies.append(latency)
-        w = max(int(np.max(st.n_results)), 1)
-        self.answers.append((np.asarray(st.n_results),
-                             np.asarray(st.result_ids)[:, :w]))
-
-
-def warm(client: Client, reqs, spans: Spans) -> str:
-    """Compile and run every program and shape the window will drive:
-    the fixed warm-up requests through both tiers, then the spatial sort
-    at every count of re-served rows a request can plausibly hold
-    (binomial over an upper estimate of the warm-up's re-serve share,
-    ±5σ)."""
-    from repro.core import schedule
-    s = client.s
-    rows = wide = 0
-    t0 = time.perf_counter()
-    for i in range(WARM_REQUESTS):
-        q = reqs.queries(i)
-        rep, _, _ = client.serve(q)
-        rows += q.shape[0]
-        wide += rep.n_reserved
-    q0 = reqs.queries(0)
-    client._step(s.wide, "wide_step", [])(q0[:s.batch])
-    n = q0.shape[0]
-    # an upper estimate, so that a share the warm-up barely saw still
-    # warms a few counts
-    p = (wide + 3) / rows
-    mu, sd = n * p, math.sqrt(n * p * (1 - p))
-    lo, hi = max(1, int(mu - 5 * sd)), min(n, int(math.ceil(mu + 5 * sd)) + 1)
-    t1 = time.perf_counter()
-    for k in range(lo, hi + 1):
-        schedule.spatial_keys(q0[:k], s.sort, s.bbox)
-        if (k - lo) % 32 == 31:
-            print(f"# warm-up: sort at {k} re-served rows, "
-                  f"{time.perf_counter() - t1:.1f}s", flush=True)
-    spans.phase(None)
-    return (f"warm-up: {WARM_REQUESTS} requests in {t1 - t0:.1f}s, re-serve "
-            f"share {p:.4f}, sort warmed for {lo}..{hi} re-served rows in "
-            f"{time.perf_counter() - t1:.1f}s")
+def driver(traffic: dict):
+    """The traffic's client driver, ``bench/drivers/<query>.py``, once
+    it has accepted the traffic file."""
+    from bench import traffic as trlib
+    trlib.check(traffic)
+    drv = importlib.import_module("bench.drivers." +
+                                  trlib.driver_name(traffic))
+    drv.check_traffic(traffic)
+    return drv
 
 
 class CompileCounter:
-    """Counts lowerings and backend compiles while ``on``."""
+    """Counts lowerings and backend compiles while ``on``, and the
+    seconds they took by program."""
 
     def __init__(self):
         import jax
         self.on = False
         self.counts = {"lowerings": 0, "compiles": 0}
+        self.seconds = {}       # program name → seconds lowering, compiling
         names = {"/jax/core/compile/jaxpr_to_mlir_module_duration":
                  "lowerings",
                  "/jax/core/compile/backend_compile_duration": "compiles"}
 
-        def listen(event, duration, **kw):
+        def listen(event, duration, fun_name="", **kw):
             if self.on and event in names:
                 self.counts[names[event]] += 1
+                self.seconds[fun_name] = \
+                    self.seconds.get(fun_name, 0.0) + duration
         jax.monitoring.register_event_duration_secs_listener(listen)
 
+    def costliest(self, n: int = 3) -> str:
+        """The ``n`` programs that took longest, with their seconds."""
+        top = sorted(self.seconds.items(), key=lambda kv: -kv[1])[:n]
+        return ", ".join(f"{k} {v:.2f}s" for k, v in top) or "none"
 
-def window(client: Client, reqs, seconds: float) -> float:
+
+def window(client, reqs, seconds: float) -> float:
     """Closed loop until ``seconds`` have passed; returns the window's
     length: from the first request's start to the completion of the last
     request started within ``seconds``."""
@@ -277,9 +163,9 @@ def window(client: Client, reqs, seconds: float) -> float:
     while i == 0 or t_end - t_begin < seconds:
         q = reqs.queries(i)
         t0 = time.perf_counter()
-        rep, no, wo = client.serve(q)
+        out = client.serve(q)
         t_end = time.perf_counter()
-        client.record(q, rep, no, wo, t_end - t0)
+        client.record(q, out, t_end - t0)
         i += 1
     return t_end - t_begin
 
@@ -293,36 +179,12 @@ def end_to_end(latencies: list, queries: int, window_s: float,
             "setup_s": setup_s}
 
 
-# -------------------------------------------------------------- checks
-
-def check_range(client: Client, reqs, fit) -> tuple[dict, int]:
-    """Every row of the window: the served count and id set against the
-    reference's, exactly. Returns ``(checks, wrong rows)``."""
-    off, ids = fit.ref_offsets, fit.ref_ids
-    wrong = 0
-    big = np.iinfo(np.int64).max
-    for i, (n_res, got) in enumerate(client.answers):
-        src = reqs.src(i)
-        n_ref = off[src + 1] - off[src]
-        w = max(int(n_ref.max(initial=0)), got.shape[1])
-        col = np.arange(w)[None, :]
-        ref = np.full((src.size, w), big, np.int64)
-        take = col < n_ref[:, None]
-        ref[take] = ids[(off[src][:, None] + col)[take]]
-        g = np.full((src.size, w), big, np.int64)
-        g[:, :got.shape[1]] = got
-        g[col >= n_res[:, None]] = big
-        g.sort(axis=1)
-        bad = (n_res != n_ref) | np.any(g != ref, axis=1)
-        wrong += int(bad.sum())
-    return {"wrong_rows": (wrong, 0)}, wrong
-
-
 # ----------------------------------------------------------------- run
 
 class Readings(NamedTuple):
     """What a per-layer metric's reader gets."""
-    counters: dict          # window totals the client kept
+    counters: dict          # window totals the client kept, and the
+    #                         window's ``lowerings`` and ``compiles``
     trace: object           # bench.trace.Trace of the window
     device_kind: str
     entries_per_leaf: int   # a leaf's padded entry slots (x, y f32 each)
@@ -336,19 +198,19 @@ def run_cell(cell: dict, cfg: dict, cfg_path: str, traffic: dict, *,
     has checked the device. ``wide_tier=False`` switches the program's
     wide re-serve off: the control that ``correct`` must fail."""
     import jax
-    from bench import deploy, trace as tracelib, traffic as trlib
-    trlib.check(traffic)
+    from bench import deploy, trace as tracelib
+    drv = driver(traffic)
     index = deploy.load_index(cfg, cfg_path, cache_dir)
     fit = deploy.load_fit(cfg, cfg_path, index, cache_dir)
     print(f"# bank: grid {fit.grid}², exact-fit {fit.exact_fit:.4f}, "
           f"pool {fit.pool.shape[0]}", flush=True)
-    server = range_server(fit, cfg)
-    reqs = trlib.draw(traffic, seed, fit.pool)
-    warm_reqs = trlib.draw(traffic, WARM_SEED, fit.pool)
+    server = drv.make_server(index, fit, cfg, traffic, cell)
+    reqs = drv.requests(traffic, seed, index, fit)
+    warm_reqs = drv.requests(traffic, WARM_SEED, index, fit)
     spans = Spans()
-    client = Client(server, spans, wide_tier=wide_tier)
-    print(f"# {warm(client, warm_reqs, spans)}", flush=True)
-    client = Client(server, spans, wide_tier=wide_tier)
+    client = drv.Client(server, spans, wide_tier=wide_tier)
+    print(f"# {drv.warm(client, warm_reqs, spans)}", flush=True)
+    client = drv.Client(server, spans, wide_tier=wide_tier)
     counter = CompileCounter()
     trace_dir = os.path.join(cache_dir, "traces", cell["name"])
     if trace_on:
@@ -362,23 +224,27 @@ def run_cell(cell: dict, cfg: dict, cfg_path: str, traffic: dict, *,
     counter.on = False
     if trace_on:
         jax.profiler.stop_trace()
-    dev = jax.devices()[0]
-    stats = dev.memory_stats() or {}
+    devs = jax.devices()[:cell["chips"]]
+    dev = devs[0]
+    # the peak of the fullest chip the cell uses
+    peak = max(int((d.memory_stats() or {}).get("peak_bytes_in_use", 0))
+               for d in devs)
     n = client.counters["queries"]
     print(f"# window: {len(client.latencies)} requests, {n} queries in "
           f"{window_s:.3f}s; inside it {counter.counts['lowerings']} "
           f"lowerings, {counter.counts['compiles']} compiles", flush=True)
+    client.counters.update(counter.counts)
     # the program's device state is freed before the reference runs
-    for leaf in jax.tree.leaves(server.state):
+    for leaf in {id(x): x for x in jax.tree.leaves(client.state)}.values():
         leaf.delete()
     del server
 
-    checks, failed = check_range(client, reqs, fit)
+    checks, failed = drv.check(client, reqs, index, fit)
     correct = all(v <= lim for v, lim in checks.values())
 
     device = {"platform": dev.platform, "kind": dev.device_kind,
               "count": len(jax.devices()),
-              "memory_peak_bytes": int(stats.get("peak_bytes_in_use", 0))}
+              "memory_peak_bytes": peak}
     # requests in the window, the sample behind p50_ms
     result = {"correct": correct, "attempted": n, "failed": failed,
               "requests": len(client.latencies)}

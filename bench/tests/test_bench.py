@@ -10,6 +10,7 @@ from __future__ import annotations
 
 import json
 import os
+import re
 import sys
 import time
 
@@ -22,6 +23,8 @@ sys.path.insert(0, ROOT)
 sys.path.insert(0, os.path.join(ROOT, "src"))
 
 from bench import deploy, peaks, run, trace, traffic as trlib  # noqa: E402
+from bench.drivers import range as range_driver  # noqa: E402
+from bench.drivers import range_insert  # noqa: E402
 
 DATA = os.path.join(os.path.dirname(os.path.abspath(__file__)), "data")
 
@@ -230,12 +233,187 @@ def _half_left_out(out):
 
 @pytest.mark.parametrize("fault", [_altered, _half_left_out])
 def test_a_broken_timed_path_is_not_correct(tiny, fault, monkeypatch):
-    orig = run.range_server
+    orig = range_driver.range_server
 
     def broken(*a):
         s = orig(*a)
         return s._replace(narrow=lambda q: fault(s.narrow(q)),
                           wide=lambda q: fault(s.wide(q)))
-    monkeypatch.setattr(run, "range_server", broken)
+    monkeypatch.setattr(range_driver, "range_server", broken)
     res, _ = _run(tiny)
     assert not res["correct"]
+
+
+# --------------------------------------------------------------- drivers
+
+def _counters(out: str) -> dict:
+    """The ``# counters:`` line a run printed."""
+    line = [ln for ln in out.splitlines() if ln.startswith("# counters: ")]
+    return json.loads(line[-1][len("# counters: "):])
+
+
+def test_traffic_without_a_driver_fails_clearly(tiny):
+    with pytest.raises(ValueError, match=r"traffic query 'knn-browse' has "
+                       r"no driver bench/drivers/knn_browse\.py"):
+        trlib.check({"query": "knn-browse", "request": 8, "draw": "pool"})
+    spec, cfg, path, cache = tiny
+    cell = {"name": "gaussian-knn", "config": "gaussian-872k",
+            "traffic": "knn", "chips": 1}
+    with pytest.raises(ValueError, match="has no driver"):
+        run.run_cell(cell, cfg, path, {"query": "knn", "request": 8,
+                                       "draw": "pool"},
+                     seed=1, seconds=0.5, trace_on=False,
+                     t_start=time.time(), e2e=spec["end_to_end"],
+                     per_layer=[], cache_dir=cache)
+
+
+@pytest.mark.parametrize("bad, msg", [
+    ({"draw": "points"}, r"traffic draw must be one of \('pool',\)"),
+    ({"draw": None}, r"range traffic lacks \['draw'\]"),
+    ({"query": "range-insert"}, r"range-insert traffic lacks \['delta_cap'"),
+])
+def test_a_driver_refuses_traffic_it_cannot_serve(bad, msg):
+    tr = {k: v for k, v in dict({"query": "range", "request": 8,
+                                 "draw": "pool"}, **bad).items()
+          if v is not None}
+    with pytest.raises(ValueError, match=msg):
+        run.driver(tr)
+
+
+def test_the_insert_stream_is_never_replayed():
+    s = trlib.Stream(np.arange(20.0).reshape(10, 2))
+    assert s.take(8, 2).tolist() == [[16.0, 17.0], [18.0, 19.0]]
+    with pytest.raises(ValueError, match="holds 10 points; the run needs 11"):
+        s.take(8, 3)
+
+
+def test_range_pool_runs_through_the_range_driver(tiny, capsys):
+    with open(os.path.join(ROOT, "bench", "traffic", "range-pool.json")) \
+            as f:
+        assert run.driver(json.load(f)) is range_driver
+    res, _ = _run(tiny)
+    got = _counters(capsys.readouterr().out)
+    assert set(got) == {"queries", "wide_rows", "leaf_accesses", "ai_rows",
+                        "refine_leaves", "lowerings", "compiles"}
+    assert got["queries"] == res["attempted"] == 64 * res["requests"]
+    assert 0 < got["wide_rows"] < got["queries"]
+
+
+# a scaled-down range-insert mix: the repack runs after the window's
+# third request, the next would need 128 more (a 0.5 s window on the CPU
+# holds about 30)
+MIXED = {"request": 64, "delta_cap": 1024, "repack_at": 512, "prestage": 500}
+
+
+def _run_mixed(tiny, seed=2**31 + 91, **kw):
+    spec, cfg, path, cache = tiny
+    cell = {"name": "gaussian-mixed", "config": "gaussian-872k",
+            "traffic": "range-insert", "chips": 1}
+    with open(os.path.join(ROOT, "bench", "traffic",
+                           cell["traffic"] + ".json")) as f:
+        tr = dict(json.load(f), **MIXED)
+    tr["inserts"] = dict(tr["inserts"], points=2000, per_request=4)
+    return run.run_cell(cell, cfg, path, tr, seed=seed, seconds=0.5,
+                        trace_on=False, t_start=time.time(),
+                        e2e=spec["end_to_end"], per_layer=[],
+                        cache_dir=cache, **kw)
+
+
+def test_mixed_run_is_correct_with_one_repack_and_nothing_compiled(
+        tiny, capsys):
+    res, lines = _run_mixed(tiny)
+    out = capsys.readouterr().out
+    got = _counters(out)
+    assert res["correct"] and res["failed"] == 0
+    assert set(res["metrics"]) == {"qps", "p50_ms", "setup_s"}
+    assert lines == ["check wrong_rows 0 limit 0"]
+    assert "# window: 1 repacks, after requests [3]\n" in out, \
+        [ln for ln in out.splitlines() if "repacks" in ln]
+    assert got["repacks"] == 1 and got["repack_s"] > 0
+    # set-up reports what the retrace after its rehearsed repack cost
+    assert re.search(r"# warm-up: .* 1 repack at \d+ staged in [\d.]+s, "
+                     r"after it \d+ lowerings, \d+ compiles \(costliest: "
+                     r".*\), the first request [\d.]+s", out)
+    # the warm-up rehearsed the repack: the window's steps after it find
+    # their programs compiled
+    assert got["lowerings"] == 0 and got["compiles"] == 0
+    assert got["inserts"] == 4 * res["requests"]
+    assert got["delta_hits"] > 0
+    assert got["probe_tests"] > 0 and got["probe_rows"] >= got["queries"]
+
+
+def test_mixed_control_without_the_wide_tier_is_not_correct(tiny):
+    res, _ = _run_mixed(tiny, wide_tier=False)
+    assert not res["correct"] and res["failed"] > 0
+
+
+def _never_staged(m):
+    """Inserts acknowledged but never staged."""
+    from repro.core import monitor
+    m.setattr(monitor.FreshServer, "insert", lambda self, points: None)
+
+
+def _hits_dropped(m):
+    """The delta buffer's hits left out of the merged answers."""
+    from repro.core import delta
+    m.setattr(delta, "merge_hybrid_result", lambda res, hits: res)
+
+
+def _shifted_after_repack(m):
+    """The repack numbers the staged points one slot off."""
+    import dataclasses
+    import jax.numpy as jnp
+    from repro.core import delta
+    orig = delta.repack
+
+    def repack(base_points, store, **kw):
+        xy = np.asarray(store.xy).copy()
+        xy[:store.n] = np.roll(xy[:store.n], 1, axis=0)
+        return orig(base_points,
+                    dataclasses.replace(store, xy=jnp.asarray(xy)), **kw)
+    m.setattr(delta, "repack", repack)
+
+
+def _in_step(fault):
+    def plant(m):
+        from repro.core import monitor
+        orig = monitor.FreshServer.step
+        m.setattr(monitor.FreshServer, "step",
+                  lambda self, q, widen=1: fault(orig(self, q, widen)))
+    plant.__name__ = fault.__name__
+    return plant
+
+
+@pytest.mark.parametrize("fault", [
+    _never_staged, _hits_dropped, _shifted_after_repack,
+    _in_step(_altered), _in_step(_half_left_out)],
+    ids=lambda f: f.__name__.strip("_"))
+def test_a_broken_mixed_path_is_not_correct(tiny, fault, monkeypatch):
+    import jax
+    with monkeypatch.context() as m:
+        fault(m)
+        # the jitted mixed step traces the planted code afresh
+        jax.clear_caches()
+        res, _ = _run_mixed(tiny)
+    jax.clear_caches()
+    assert not res["correct"] and res["failed"] > 0
+
+
+def test_mixed_readers():
+    r = run.Readings(counters={"queries": 512, "repack_s": 2.0,
+                               "lowerings": 0, "probe_tests": 512 * 100,
+                               "probe_staged": 100, "probe_rows": 512},
+                     trace=_trace([("delta_probe", 0, 1000)], [],
+                                  window=(0, 40 * 10**9)),
+                     device_kind="TPU v5 lite", entries_per_leaf=200)
+    assert run.reader("repack_share")(r) == pytest.approx(5.0)
+    assert run.reader("window_lowerings")(r) == 0
+    # bytes-bound: 100 staged x 8 B + 512 rows x 16 B over 1,000 ns
+    assert run.reader("delta_probe_roofline")(r) == pytest.approx(
+        100 * (800 + 8192) / 819e9 / 1e-6)
+    none = r._replace(counters={"queries": 512, "probe_tests": 0})
+    for m in ("repack_share", "window_lowerings", "delta_probe_roofline"):
+        assert run.reader(m)(none) is None
+    assert run.reader("delta_probe_roofline")(
+        r._replace(trace=_trace([("fusion", 0, 10)], []))) is None
+    assert run.reader("repack_share")(r._replace(trace=None)) is None

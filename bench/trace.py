@@ -20,7 +20,8 @@ from typing import NamedTuple
 
 WINDOW = "window"
 # host spans the harness writes around each call into a layer
-SPANS = ("schedule", "narrow_step", "to_host", "wide_step", "merge")
+SPANS = ("schedule", "narrow_step", "to_host", "wide_step", "merge",
+         "insert", "repack")
 OPS_LINE = "XLA Ops"
 _CHIP_PLANE = re.compile(r"^/device:[A-Z]+:\d+$")
 _HOST_PLANE = "/host:CPU"
